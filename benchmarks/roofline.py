@@ -5,21 +5,38 @@
   memory     = HLO_bytes / HBM_bw                (per device)
   collective = wire_bytes / (links x link_bw)    (per device)
 
-TPU v5e constants: 197 TFLOP/s bf16, 819 GB/s HBM, ~50 GB/s/link ICI.
+Peaks are per chip, keyed by the ``device_kind`` JAX reports; the dry-run
+lowers for the production mesh of ``launch/mesh.py``, which is TPU v5e
+(Google Cloud "TPU v5e": 197 TFLOP/s bf16, 819 GB/s HBM; ~50 GB/s/link
+ICI). A kind with no entry is an error, never another chip's peaks.
 MODEL_FLOPS = 6·N·D (dense; N_active for MoE) for the useful-compute ratio.
 """
 from __future__ import annotations
 
 import json
-import sys
 from pathlib import Path
+from typing import NamedTuple
 
 from repro.configs import ASSIGNED_ARCHS, INPUT_SHAPES, get_config
 
-PEAK_FLOPS = 197e12        # bf16 per chip
-HBM_BW = 819e9             # B/s per chip
-LINK_BW = 50e9             # B/s per ICI link
-N_LINKS = 4                # 2D torus: 4 links per chip
+
+class Peaks(NamedTuple):
+    flops: float     # bf16 FLOP/s per chip
+    hbm_bw: float    # B/s per chip
+    link_bw: float   # B/s per ICI link
+    n_links: int     # ICI links per chip
+
+
+PEAKS = {"TPU v5 lite": Peaks(197e12, 819e9, 50e9, 4)}   # v5e, 2D torus
+PRODUCTION_KIND = "TPU v5 lite"   # what make_production_mesh describes
+
+
+def peaks(kind: str) -> Peaks:
+    if kind not in PEAKS:
+        raise ValueError(f"roofline: no peaks for device kind {kind!r} "
+                         f"(known: {sorted(PEAKS)})")
+    return PEAKS[kind]
+
 
 DRYRUN_DIR = Path(__file__).resolve().parents[1] / "experiments" / "dryrun"
 
@@ -62,9 +79,10 @@ def analyze(rec: dict) -> dict:
     bytes_dev = bytes_per_device(cfg, shape, n_dev, agg)
     wire = rec["collectives"].get("total_wire_bytes",
                                   rec["collectives"]["total_bytes"])
-    t_comp = flops_dev / PEAK_FLOPS
-    t_mem = bytes_dev / HBM_BW
-    t_coll = wire / (N_LINKS * LINK_BW)
+    pk = peaks(rec.get("device_kind", PRODUCTION_KIND))
+    t_comp = flops_dev / pk.flops
+    t_mem = bytes_dev / pk.hbm_bw
+    t_coll = wire / (pk.n_links * pk.link_bw)
     terms = {"compute_s": t_comp, "memory_s": t_mem, "collective_s": t_coll}
     dom = max(terms, key=terms.get)
     mf_dev = model_flops(cfg, shape, shape.kind) / n_dev

@@ -61,6 +61,7 @@ def main() -> None:
     vkey = {"fig1": 2, "fig2": 2, "fig3": 2, "fig4": 2, "fig5": 2,
             "ablations": 2}
     print("name,us_per_call,derived", flush=True)
+    failed = []
     for name, fn in suites.items():
         if only and name not in only:
             continue
@@ -71,10 +72,13 @@ def main() -> None:
                 key = f"{name}:v{vkey[name]}:r{rounds}" if name in vkey \
                     else f"{name}:r{rounds}"
                 cached_suite(key, fn)
-        except Exception as e:  # keep the harness running
+        except Exception as e:  # run the other suites, then fail
+            failed.append(name)
             print(f"{name}/ERROR,0.0,{type(e).__name__}:{e}",
                   file=sys.stdout, flush=True)
     sys.stdout.flush()
+    if failed:
+        raise SystemExit(f"benchmarks.run: suites raised: {','.join(failed)}")
 
 
 if __name__ == "__main__":

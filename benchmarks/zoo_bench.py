@@ -8,13 +8,15 @@ full D is ever replicated? Measured as rounds/sec on the host mesh
 
 Methodology:
 
-- Every measurement runs in a CHILD process so the 8-device XLA host flag
-  never leaks into the caller (the bench harness keeps 1 device).
+- On the CPU every measurement runs in a CHILD process so the 8-device
+  XLA host flag never leaks into the caller (the bench harness keeps 1
+  device). On a TPU the rows run in the calling process, which holds the
+  chip, on a mesh of the local chips.
 - Default rows are CI-scale: a parity gate (the 16k-element geometry of
-  tests/test_zoo.py — the sharded round must stay BITWISE equal to the
-  single-device reference over a 2-round chain) plus smoke-config rounds
-  for two architectures. CI asserts the deterministic parity flag, never
-  a timing ratio (the PR-3 convention).
+  tests/test_zoo.py — the sharded round must stay within the parity
+  tolerances of ``repro.engine.parity`` of the single-device reference
+  over a 2-round chain) plus smoke-config rounds for two architectures.
+  CI asserts the parity flag, never a timing ratio (the PR-3 convention).
 - ``--full`` regenerates the zoo-scale row: the gemma2-2b FULL config
   (2.614B parameters — the ≥1B acceptance config) with the wide-chunk
   geometry D_c=16384, S_c=32, κ_c=8. Parameters stay 8-way sharded
@@ -28,10 +30,11 @@ sit NEXT TO the surrogate rows: the same round driven by genuine eq. 3
 gradients of the scanned stacked-layer model, computed parameter-sharded
 with cotangents landing directly in the owned chunk rows (no host
 round-trip, no full-D gather). CI-scale train rows run fresh each time —
-``parity-gemma2-smoke`` gates a 3-round REAL-gradient chain bitwise
-against the single-device oracle — and every row reports peak process
-RSS (`peak_rss_mb`; on the host-device mesh peak RSS IS the device
-memory bound). The ≥1B real-backward row is cached under ``zoo:v2:full``
+``parity-gemma2-smoke`` gates a 3-round REAL-gradient chain against the
+single-device oracle within the same tolerances — and every row reports
+peak process RSS (`peak_rss_mb`; on the host-device mesh peak RSS IS the
+device memory bound), plus the device's own peak where it reports one
+(`peak_device_mb`). The ≥1B real-backward row is cached under ``zoo:v2:full``
 and regenerated by ``--full``.
 """
 from __future__ import annotations
@@ -44,25 +47,51 @@ import textwrap
 
 from benchmarks.common import CACHE_PATH, cached_rows, emit
 
-SRC = os.path.join(os.path.dirname(__file__), "..", "src")
+ROOT = os.path.join(os.path.dirname(os.path.abspath(__file__)), "..")
+SRC = os.path.join(ROOT, "src")
 
 FULL_KEY = "zoo:v1:full"          # surrogate-grads ≥1B row
 TRAIN_KEY = "zoo:v3"              # real-backward CI rows (state-carry API)
 TRAIN_FULL_KEY = "zoo:v2:full"    # real-backward ≥1B row
 
-CHILD = textwrap.dedent("""
-    import os
-    os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
-    os.environ.setdefault("JAX_PLATFORMS", "cpu")
-    import json, resource, sys, time
-    import jax, jax.numpy as jnp, numpy as np
+def _mesh(spec: dict):
+    """The spec's workers x model mesh where the local devices make it up
+    (the 8-device host mesh); on a TPU with another device count, every
+    local chip (``make_zoo_mesh`` defaults)."""
+    import jax
+    from repro.launch.mesh import make_zoo_mesh
+    if spec["workers"] * spec["mp"] == len(jax.devices()):
+        return make_zoo_mesh(spec["workers"], spec["mp"])
+    return make_zoo_mesh()
+
+
+def _peak_fields() -> dict:
+    """Peak process RSS (on the host-device mesh it IS the device memory
+    bound) and, where the device reports it, its peak bytes in use."""
+    import resource
+
+    import jax
+    out = {"peak_mb": resource.getrusage(
+        resource.RUSAGE_SELF).ru_maxrss / 1024.0}
+    stats = jax.devices()[0].memory_stats() or {}
+    if "peak_bytes_in_use" in stats:
+        out["peak_device_mb"] = stats["peak_bytes_in_use"] / 2**20
+    return out
+
+
+def run_round(spec: dict) -> dict:
+    """One surrogate-gradient zoo row (engine/zoo.py) on ``_mesh(spec)``."""
+    import time
+
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
     from jax.sharding import NamedSharding
     from repro.core.obcsaa import OBCSAAConfig
+    from repro.engine import parity
     from repro.engine.zoo import build_zoo_round
-    from repro.launch.mesh import make_zoo_mesh
 
-    spec = json.loads(sys.argv[1])
-    mesh = make_zoo_mesh(spec["workers"], spec["mp"])
+    mesh = _mesh(spec)
     if spec.get("arch"):
         from repro.configs import get_config, get_smoke_config
         from repro.models.registry import build_model
@@ -90,39 +119,37 @@ CHILD = textwrap.dedent("""
     jax.block_until_ready(params)
     wall = time.time() - t0
 
-    peak_mb = resource.getrusage(resource.RUSAGE_SELF).ru_maxrss / 1024.0
     out = {"D": D, "D_pad": zr.D_pad, "n_chunks": zr.n_chunks,
            "workers": zr.U, "mp": zr.n_model, "rounds": spec["rounds"],
-           "compile_s": compile_s, "wall_s": wall, "peak_mb": peak_mb,
+           "compile_s": compile_s, "wall_s": wall, **_peak_fields(),
            "ghat_norm": float(st.ghat_norm),
            "finite": bool(np.isfinite(float(st.ghat_norm)))}
     if spec.get("parity"):
         rc = zr.chunk_params(jnp.zeros((D,), jnp.float32))
         for t in range(1 + spec["rounds"]):
             rc, _ = zr.reference_round(rc, t, key, 1e-4, 10.0, 0.05)
-        out["parity"] = bool(np.array_equal(np.asarray(params),
-                                            np.asarray(rc)))
-    print("ZOO_RESULT " + json.dumps(out))
-""")
+        out["parity"] = parity.compare_leaf(
+            "master", params, rc, parity.TOLERANCES["master"]).ok
+    return out
 
-# Real sharded backward passes through the same round tail
-# (engine/zoo_train.py, DESIGN.md §16): genuine eq. 3 gradients of the
-# scanned stacked-layer model, produced already in the compressor's
-# chunk layout — no host round-trip, no full-D gather.
-CHILD_TRAIN = textwrap.dedent("""
-    import os
-    os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
-    os.environ.setdefault("JAX_PLATFORMS", "cpu")
-    import json, resource, sys, time
-    import jax, jax.numpy as jnp, numpy as np
+
+def run_train(spec: dict) -> dict:
+    """One real-backward zoo-train row (engine/zoo_train.py, DESIGN.md
+    §16): genuine eq. 3 gradients of the scanned stacked-layer model,
+    produced already in the compressor's chunk layout — no host
+    round-trip, no full-D gather."""
+    import time
+
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
     from repro.configs import get_config, get_smoke_config
     from repro.core.obcsaa import OBCSAAConfig
+    from repro.engine import parity
     from repro.engine.zoo_train import build_zoo_train_round
-    from repro.launch.mesh import make_zoo_mesh
     from repro.models.registry import build_model
 
-    spec = json.loads(sys.argv[1])
-    mesh = make_zoo_mesh(spec["workers"], spec["mp"])
+    mesh = _mesh(spec)
     cfg = (get_smoke_config if spec["smoke"] else get_config)(spec["arch"])
     model = build_model(cfg)
     ob = OBCSAAConfig(**spec["ob"])
@@ -132,9 +159,7 @@ CHILD_TRAIN = textwrap.dedent("""
                                opt_kwargs=spec.get("opt_kwargs"),
                                error_feedback=spec.get("error_feedback",
                                                        False))
-    params = model.init(jax.random.PRNGKey(0))
-    chunked0 = zr.chunk_params(params)
-    del params
+    chunked0 = zr.chunk_params(model.init(jax.random.PRNGKey(0)))
     state = zr.shard_state(zr.init_state(chunked0))
     if not spec.get("parity"):
         del chunked0          # only the oracle chain needs the host copy
@@ -155,7 +180,6 @@ CHILD_TRAIN = textwrap.dedent("""
                                    0.05)
     jax.block_until_ready(state.master)
     wall = time.time() - t0
-    peak_mb = resource.getrusage(resource.RUSAGE_SELF).ru_maxrss / 1024.0
     finite = bool(np.isfinite(float(st.loss))
                   and np.isfinite(float(st.ghat_norm))
                   and all(np.isfinite(np.asarray(x)).all()
@@ -163,7 +187,7 @@ CHILD_TRAIN = textwrap.dedent("""
     out = {"D": zr.D, "D_pad": zr.D_pad, "n_chunks": zr.n_chunks,
            "workers": zr.U, "mp": zr.n_model,
            "rounds": spec["rounds"], "compile_s": compile_s,
-           "wall_s": wall, "peak_mb": peak_mb,
+           "wall_s": wall, **_peak_fields(),
            "loss": float(st.loss), "finite": finite}
     if spec.get("parity"):
         # parity covers EVERY carry leaf: master + optimizer moments +
@@ -172,19 +196,33 @@ CHILD_TRAIN = textwrap.dedent("""
         for t in range(1 + spec["rounds"]):
             rc, _ = zr.reference_round_train(rc, raw, t, key, 1e-4, 10.0,
                                              0.05)
-        out["parity"] = bool(all(
-            np.array_equal(np.asarray(a), np.asarray(b))
-            for a, b in zip(jax.tree_util.tree_leaves(state),
-                            jax.tree_util.tree_leaves(rc))))
-    print("ZOO_RESULT " + json.dumps(out))
+        out["parity"] = all(r.ok for r in parity.compare_states(
+            state, rc, master0=chunked0))
+    return out
+
+
+# A child runs one row on the 8-device host mesh; the flag never leaks
+# into the caller (the bench harness keeps 1 device).
+CHILD = textwrap.dedent("""
+    import json, os, sys
+    os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
+    from benchmarks import zoo_bench
+    res = getattr(zoo_bench, sys.argv[1])(json.loads(sys.argv[2]))
+    print("ZOO_RESULT " + json.dumps(res))
 """)
 
 
-def _child(spec: dict, timeout: int, script: str = CHILD) -> dict:
+def _child(spec: dict, timeout: int, fn=run_round) -> dict:
+    """Run one row: in this process on a TPU (a child could not reach the
+    chip this process holds), else in a child on the host mesh."""
+    import jax
+    if jax.default_backend() == "tpu":
+        return fn(spec)
     env = dict(os.environ)
-    env["PYTHONPATH"] = SRC + os.pathsep + env.get("PYTHONPATH", "")
-    env["JAX_PLATFORMS"] = "cpu"
-    r = subprocess.run([sys.executable, "-c", script, json.dumps(spec)],
+    env["PYTHONPATH"] = os.pathsep.join(
+        [SRC, ROOT] + ([env["PYTHONPATH"]] if env.get("PYTHONPATH") else []))
+    r = subprocess.run([sys.executable, "-c", CHILD, fn.__name__,
+                        json.dumps(spec)],
                        env=env, capture_output=True, text=True,
                        timeout=timeout)
     if r.returncode != 0:
@@ -202,6 +240,8 @@ def _row(name: str, res: dict) -> tuple:
                f"finite={res['finite']}")
     if "peak_mb" in res:
         derived += f";peak_rss_mb={res['peak_mb']:.0f}"
+    if "peak_device_mb" in res:
+        derived += f";peak_device_mb={res['peak_device_mb']:.0f}"
     if "loss" in res:
         derived += f";loss={res['loss']:.4f}"
     if "parity" in res:
@@ -234,24 +274,24 @@ def _smoke_rows():
 
 
 def _train_smoke_rows():
-    """Real-backward CI rows: a BITWISE parity gate (sharded round chain
-    with genuine scanned-layer gradients vs the single-device oracle),
-    the stateful-optimizer gate (adam moments + per-worker EF residuals
-    as sharded carries, every carry leaf bitwise — DESIGN.md §17), plus
-    a second-arch timing row with peak RSS."""
+    """Real-backward CI rows: a parity gate (sharded round chain with
+    genuine scanned-layer gradients vs the single-device oracle), the
+    stateful-optimizer gate (adam moments + per-worker EF residuals as
+    sharded carries, every carry leaf within tolerance — DESIGN.md §17),
+    plus a second-arch timing row with peak RSS."""
     rows = [_row("zoo-train/parity-gemma2-smoke", _child(
         {"arch": "gemma2-2b", "smoke": True, "ob": PARITY_OB, "rounds": 2,
          "workers": 4, "mp": 2, "batch": 2, "seq": 32, "parity": True},
-        timeout=1800, script=CHILD_TRAIN))]
+        timeout=1800, fn=run_train))]
     rows.append(_row("zoo-train/parity-opt-ef-smoke", _child(
         {"arch": "gemma2-2b", "smoke": True, "ob": PARITY_OB, "rounds": 2,
          "workers": 4, "mp": 2, "batch": 2, "seq": 32, "parity": True,
          "optimizer": "adam", "error_feedback": True},
-        timeout=1800, script=CHILD_TRAIN)))
+        timeout=1800, fn=run_train)))
     rows.append(_row("zoo-train/mamba2-2.7b-smoke", _child(
         {"arch": "mamba2-2.7b", "smoke": True, "ob": SMOKE_OB, "rounds": 2,
          "workers": 4, "mp": 2, "batch": 2, "seq": 32},
-        timeout=1800, script=CHILD_TRAIN)))
+        timeout=1800, fn=run_train)))
     return rows
 
 
@@ -271,7 +311,7 @@ def _train_full_rows():
     fits host RAM when every simulated device pays them at once."""
     res = _child({"arch": "gemma2-2b", "smoke": False, "ob": FULL_OB,
                   "rounds": 1, "workers": 2, "mp": 4, "batch": 1,
-                  "seq": 32}, timeout=14400, script=CHILD_TRAIN)
+                  "seq": 32}, timeout=14400, fn=run_train)
     assert res["D"] >= 1_000_000_000, res
     return [_row("zoo-train/gemma2-2b-2.6B", res)]
 

@@ -17,6 +17,7 @@ import jax.numpy as jnp
 from repro.configs import TrainConfig, get_smoke_config
 from repro.data import token_stream
 from repro.launch import steps as steps_lib
+from repro.launch.mesh import auto_mesh
 from repro.models.registry import build_model
 
 
@@ -27,7 +28,7 @@ def main():
     ap.add_argument("--agg", default="obcsaa", choices=["obcsaa", "mean"])
     args = ap.parse_args()
 
-    mesh = jax.make_mesh((4, 2), ("data", "model"))
+    mesh = auto_mesh((4, 2), ("data", "model"))
     cfg = get_smoke_config(args.arch)
     model = build_model(cfg)
     tcfg = TrainConfig(aggregation=args.agg, cs_chunk=1024, cs_measure=256,

@@ -9,18 +9,10 @@ physical meshes (``repro.launch.mesh``):
 - ``repro.dist.collectives`` — worker-axis collectives. The over-the-air
   MAC superposition (paper eq. 8-12) IS ``psum`` over the mesh axes that
   enumerate FL workers (DESIGN.md §3).
-- ``repro.dist.compat`` — forward-compat shims: the codebase is written
-  against the jax>=0.6 sharding surface (``jax.shard_map``,
-  ``jax.set_mesh``, ``jax.sharding.get_abstract_mesh``); on older jax
-  those names are backported here. Installed on import, idempotent.
 """
-from repro.dist import compat as _compat
-
-_compat.install()
-
-from repro.dist import collectives  # noqa: E402
-from repro.dist.sharding import (best_spec, constrain,  # noqa: E402
-                                 infer_param_sharding, param_shard_dims)
+from repro.dist import collectives
+from repro.dist.sharding import (best_spec, constrain, infer_param_sharding,
+                                 param_shard_dims)
 
 __all__ = ["best_spec", "collectives", "constrain", "infer_param_sharding",
            "param_shard_dims"]

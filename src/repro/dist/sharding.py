@@ -4,7 +4,8 @@ Three entry points, consumed across core, models, and launch:
 
 - ``constrain(x, axes)`` — ``with_sharding_constraint`` that degrades to a
   no-op when there is no ambient mesh, an axis is absent/manual, or a dim
-  isn't divisible. Model code calls it unconditionally; the same forward
+  isn't divisible; a constraint it does emit and the compiler refuses is
+  an error. Model code calls it unconditionally; the same forward
   runs unsharded on one CPU device and sharded on the production mesh.
 - ``best_spec(shape, hints, mesh)`` — per-dim axis choice from priority
   hint lists like ``["data", None]``, preferring the largest divisible
@@ -24,9 +25,7 @@ from __future__ import annotations
 from typing import Sequence
 
 import jax
-from jax.sharding import NamedSharding, PartitionSpec as P
-
-from repro.dist import compat
+from jax.sharding import AxisType, NamedSharding, PartitionSpec as P
 
 # Pytree keys whose subtrees hold layer-stacked leaves: dim 0 is the
 # lax.scan axis, not a shardable weight dim.
@@ -34,13 +33,13 @@ STACKED_KEYS = ("layers", "enc_layers")
 
 
 def _ambient():
-    """(concrete mesh or None, frozenset of manual axis names)."""
+    """(ambient abstract mesh or None, frozenset of manual axis names)."""
     view = jax.sharding.get_abstract_mesh()
-    if view is None or getattr(view, "empty", True):
+    if view is None or view.empty:
         return None, frozenset()
     manual = frozenset(a for a, t in zip(view.axis_names, view.axis_types)
-                       if "Manual" in str(t))
-    return compat._unwrap(view), manual
+                       if t == AxisType.Manual)
+    return view, manual
 
 
 def _axis_sizes(mesh) -> dict:
@@ -59,10 +58,6 @@ def constrain(x, axes):
         return x
     mesh, manual = _ambient()
     if mesh is None:
-        return x
-    if manual and compat.LEGACY_SHARD_MAP:
-        # 0.4.x SPMD partitioner aborts on constraints inside a
-        # partial-manual shard_map body; drop the hint there.
         return x
     sizes = _axis_sizes(mesh)
     axes = tuple(axes)[:len(shape)]
@@ -83,13 +78,7 @@ def constrain(x, axes):
                      else (keep[0] if keep else None))
     if all(p is None for p in parts):
         return x
-    try:
-        return jax.lax.with_sharding_constraint(
-            x, NamedSharding(mesh, P(*parts)))
-    except Exception:
-        # e.g. a constraint the current shard_map/jit context can't express;
-        # a sharding hint must never turn into a hard failure.
-        return x
+    return jax.lax.with_sharding_constraint(x, NamedSharding(mesh, P(*parts)))
 
 
 def best_spec(shape: Sequence[int], hints, mesh) -> P:
@@ -101,7 +90,6 @@ def best_spec(shape: Sequence[int], hints, mesh) -> P:
     worker-axis product ``("pod", "data")`` on 3-axis meshes when that
     larger factor still divides (global batch is sharded over ALL workers,
     DESIGN.md §3). No candidate fits -> the dim is replicated."""
-    mesh = compat._unwrap(mesh)
     sizes = _axis_sizes(mesh)
     used = set()
     parts = []
@@ -144,7 +132,6 @@ def infer_batch_sharding(tree, mesh, *, dim: int = 0):
     axis — no cross-arm collectives — so arm-sharded placement turns the
     sweep into per-device lane groups (DESIGN.md §14). Scalars and
     non-divisible leaves replicate, which is always correct."""
-    mesh = compat._unwrap(mesh)
 
     def spec_of(leaf):
         shape = tuple(getattr(leaf, "shape", ()))
@@ -190,7 +177,6 @@ def param_shard_dims(tree, mesh, *, model_axis: str = "model",
     leaf-for-leaf congruent with ``tree``). Consumed by the zoo-train
     layout and layer resolvers, which need the raw dim to slice/gather
     along rather than a NamedSharding."""
-    mesh = compat._unwrap(mesh)
     msize = _axis_sizes(mesh).get(model_axis, 1)
 
     def dim_of(path, leaf):
@@ -216,7 +202,6 @@ def infer_param_sharding(tree, mesh, *, model_axis: str = "model",
     ``lax.scan``) keep dim 0 whole — the scan axis is sliced one layer per
     step and sharding it would split layers across devices instead of
     splitting weights within a layer."""
-    mesh = compat._unwrap(mesh)
     msize = _axis_sizes(mesh).get(model_axis, 1)
 
     def spec_of(path, leaf):

@@ -99,7 +99,10 @@ class EngineRun:
 
     def init(self, arm: Optional[Arms] = None):
         arm = arm if arm is not None else single_arm(self.cfg)
-        return self.fns.init_state(self._params0, arm), arm
+        # run_chunk donates the state: give it its own copy of the params,
+        # so the caller's arrays outlive the first chunk
+        params = jax.tree_util.tree_map(jnp.copy, self._params0)
+        return self.fns.init_state(params, arm), arm
 
     def run_chunk(self, state, arm, t0: int, n: int, vmapped=False):
         """Advance ``n`` rounds from ``t0`` in one device call. Returns

@@ -131,7 +131,8 @@ def backproject_packed(x: jnp.ndarray, plus: jnp.ndarray, minus: jnp.ndarray,
             f"backproject_packed: bit-planes must be uint32 "
             f"(n, S//{PACK}) = ({n}, {s // PACK}); got {plus.dtype} "
             f"{plus.shape} / {minus.dtype} {minus.shape} (DESIGN.md §13)")
-    bn, bd, bs = tiles if tiles else (min(BN, n), min(BD, d), min(BS, s))
+    # the bit-plane blocks span the whole packed width (see cs_project)
+    bn, bd, bs = tiles if tiles else (min(BN, n), min(BD, d), s)
     _validate("backproject_packed", n, s, d, bn, bd, bs, packed=True)
     n_bs = s // bs
     grid = (n // bn, d // bd, n_bs)

@@ -113,7 +113,7 @@ def _proj_pack_resid_kernel(x_ref, phi_ref, y_ref, plus_ref, minus_ref,
     @pl.when(k == n_bd - 1)
     def _():
         sb = acc_ref[...] >= 0                       # shared sign predicate
-        yb = unpack_bits(y_ref[...], jnp.bool_)
+        yb = unpack_bits(y_ref[...]) != 0
         plus_ref[...] = pack_bool(yb & ~sb)
         minus_ref[...] = pack_bool(sb & ~yb)
 
@@ -159,7 +159,10 @@ def project(phi: jnp.ndarray, chunks: jnp.ndarray, *, mode: str = "sign",
         raise ValueError(f"cs_project: phi {phi.shape} does not contract "
                          f"with chunks {chunks.shape} (need phi (S, D))")
     packed = mode in _PACKED_MODES
-    bn, bs, bd = tiles if tiles else (min(BN, n), min(BS, s), min(BD, d))
+    # a packed block spans the whole packed width (S//32 words): a narrower
+    # one breaks the TPU's (8, 128) block rule
+    bn, bs, bd = tiles if tiles else (min(BN, n), s if packed else min(BS, s),
+                                      min(BD, d))
     validate_tiling("cs_project", n, s, d, bn, bs, bd, packed=packed)
     if mode in _Y_MODES and y is None:
         raise ValueError(f"cs_project: mode {mode!r} needs y")

@@ -89,7 +89,8 @@ def cs_project(phi, chunks, interpret=None):
 def topk_select(chunks, k, interpret=None):
     """Per-row top-k by magnitude -> (values, mask)."""
     interpret = _interpret() if interpret is None else interpret
-    chunks, n = _pad_rows(chunks, min(_tk.BN, max(1, chunks.shape[0])))
+    chunks, n = _pad_rows(chunks, _tk.row_tile(max(1, chunks.shape[0]),
+                                               chunks.shape[1]))
     val, mask = _tk.topk_select(chunks, k, interpret=interpret)
     return val[:n], mask[:n]
 

@@ -13,9 +13,11 @@ U ≥ 8192 sweeps stream through without materialising anything but the
 Per-batch-row scalar coefficients arrive packed as a (B, 8) f32 matrix
 (``pack order: Ktot, ρ1, A, E, N``; see ``prefix_rt``) so one BlockSpec
 feeds every tile. In interpret mode the default tile spans the full U
-extent, making the in-kernel cumsum + formula the *same ops* as the jnp
-reference path — bit-for-bit parity (tests/test_sched.py), mirroring the
-fused-decode tiling policy of DESIGN.md §9.
+extent (padded to whole 128-lane segments), mirroring the fused-decode
+tiling policy of DESIGN.md §9. The in-tile prefix sums are triangular
+matmuls (Mosaic lowers no cumsum); they are exact wherever the partial
+sums of K are, as with integer sample counts, and then the kernel is
+bit-for-bit with the jnp cumsum sweep (tests/test_sched.py).
 """
 from __future__ import annotations
 
@@ -28,6 +30,7 @@ from jax.experimental.pallas import tpu as pltpu
 
 BB = 8        # batch rows per tile
 BU = 512      # prefix positions per tile (lane-aligned)
+LANE = 128    # prefix positions per in-tile triangular matmul
 N_COEF = 8    # packed per-row scalar coefficients (5 used, lane padding)
 
 
@@ -49,16 +52,29 @@ def _prefix_kernel(caps_ref, k_ref, coef_ref, out_ref, s2_ref, *, bu):
     def _():
         s2_ref[...] = jnp.zeros_like(s2_ref)
 
-    k = k_ref[...].astype(jnp.float32)                  # (bb, bu)
-    s2 = s2_ref[...] + jnp.cumsum(k, axis=-1)
-    base = (j * bu + 1).astype(jnp.float32)
-    s1 = jax.lax.broadcasted_iota(jnp.float32, k.shape, 1) + base
+    # Mosaic has no cumsum: each LANE-wide segment's inclusive prefix sum
+    # is one matmul with an upper-triangular ones matrix, plus the carried
+    # running sum (exact wherever the partial sums are, e.g. integer K)
+    w = min(LANE, bu)
+    rows = jax.lax.broadcasted_iota(jnp.int32, (w, w), 0)
+    cols = jax.lax.broadcasted_iota(jnp.int32, (w, w), 1)
+    tri = (rows <= cols).astype(jnp.float32)
     coef = coef_ref[...]
-    out_ref[...] = prefix_rt(
-        s1, s2, caps_ref[...].astype(jnp.float32),
-        ktot=coef[:, 0:1], rho1=coef[:, 1:2], A=coef[:, 2:3],
-        E=coef[:, 3:4], N=coef[:, 4:5]).astype(out_ref.dtype)
-    s2_ref[...] = s2[:, -1:]
+    s2 = s2_ref[...]                                    # (bb, 1)
+    for c in range(bu // w):
+        seg = pl.ds(c * w, w)
+        k = k_ref[:, seg].astype(jnp.float32)           # (bb, w)
+        s2 = s2 + jnp.dot(k, tri, precision=jax.lax.Precision.HIGHEST,
+                          preferred_element_type=jnp.float32)
+        base = (j * bu + c * w + 1).astype(jnp.float32)
+        s1 = jax.lax.broadcasted_iota(jnp.int32, k.shape, 1).astype(
+            jnp.float32) + base
+        out_ref[:, seg] = prefix_rt(
+            s1, s2, caps_ref[:, seg].astype(jnp.float32),
+            ktot=coef[:, 0:1], rho1=coef[:, 1:2], A=coef[:, 2:3],
+            E=coef[:, 3:4], N=coef[:, 4:5]).astype(out_ref.dtype)
+        s2 = s2[:, -1:]
+    s2_ref[...] = s2
 
 
 def prefix_eval(caps_sorted: jnp.ndarray, k_sorted: jnp.ndarray,
@@ -75,7 +91,11 @@ def prefix_eval(caps_sorted: jnp.ndarray, k_sorted: jnp.ndarray,
     if tiles:
         bb, bu = tiles
     else:
-        bb, bu = min(BB, B), (U if interpret else min(BU, U))
+        full = -(-U // LANE) * LANE        # U padded to whole segments
+        bb, bu = min(BB, B), (full if interpret else min(BU, full))
+    if bu > LANE and bu % LANE:
+        raise ValueError(f"prefix_eval: U-tile {bu} must be <= {LANE} or a "
+                         f"multiple of it")
     pad_b, pad_u = (-B) % bb, (-U) % bu
     if pad_b or pad_u:
         caps_sorted = jnp.pad(caps_sorted, ((0, pad_b), (0, pad_u)),

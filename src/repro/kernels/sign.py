@@ -24,12 +24,13 @@ bit-for-bit equal to the f32 sign paths: identical values into identical
 ``dot_general``/einsum contractions.
 
 Everything here is plain jnp so it is usable both outside kernels and
-inside Pallas kernel bodies (interpret mode on CPU; on TPU the
-reshape/shift formulation lowers through Mosaic with lane padding for the
-narrow packed axis).
+inside Pallas kernel bodies (interpret mode on CPU, Mosaic on TPU). The
+bit arithmetic runs in int32 and bitcasts to uint32 at the edges: Mosaic
+neither reduces nor converts unsigned integers.
 """
 from __future__ import annotations
 
+import jax
 import jax.numpy as jnp
 
 PACK = 32  # signs per uint32 word
@@ -64,15 +65,26 @@ def pack_signs(x: jnp.ndarray) -> jnp.ndarray:
 
 
 def pack_bool(bits: jnp.ndarray) -> jnp.ndarray:
-    """(..., S) bool -> (..., S//32) uint32 (kernel-epilogue helper)."""
-    w = packed_width(bits.shape[-1])
-    b = bits.reshape(bits.shape[:-1] + (w, PACK)).astype(jnp.uint32)
-    return jnp.sum(b << _shifts(), axis=-1, dtype=jnp.uint32)
+    """(..., S) bool -> (..., S//32) uint32 (kernel-epilogue helper).
+
+    The sign axis is moved to the sublanes before it is split into words:
+    Mosaic cannot split the lane axis of a (rows, S) tile. The 32 shifted
+    bits of a word are distinct powers of two, so their int32 sum is the
+    word's bit pattern exactly (bit 31 wraps to the sign bit)."""
+    s = bits.shape[-1]
+    w = packed_width(s)
+    rows = bits.reshape((-1, s)).astype(jnp.int32).T      # (S, m)
+    b = rows.reshape((w, PACK, rows.shape[-1]))
+    shifts = jax.lax.broadcasted_iota(jnp.int32, b.shape, 1)
+    words = jnp.sum(b << shifts, axis=1).T                # (m, w)
+    return jax.lax.bitcast_convert_type(
+        words.reshape(bits.shape[:-1] + (w,)), jnp.uint32)
 
 
 def unpack_bits(packed: jnp.ndarray, dtype=jnp.int32) -> jnp.ndarray:
     """(..., W) uint32 -> (..., W*32) {0, 1} in ``dtype``."""
-    bits = (packed[..., None] >> _shifts()) & jnp.uint32(1)
+    words = jax.lax.bitcast_convert_type(packed, jnp.int32)
+    bits = (words[..., None] >> jnp.arange(PACK, dtype=jnp.int32)) & 1
     return bits.reshape(packed.shape[:-1] + (-1,)).astype(dtype)
 
 

@@ -6,21 +6,36 @@ jax device state.
 from __future__ import annotations
 
 import jax
+from jax.sharding import AxisType
+
+
+def auto_mesh(shape, axes, *, devices=None):
+    """``jax.make_mesh`` with every axis ``AxisType.Auto``.
+
+    Newer jax defaults to ``Explicit`` axes, which put shardings into the
+    array types and refuse reshapes of sharded dims (the flat-layout
+    views of ``dist/flat_layout.py``) even on a one-device mesh. The repo
+    places data with ``NamedSharding``/``shard_map`` and lets the
+    partitioner propagate the rest, which is what ``Auto`` means.
+    ``devices`` defaults to ``jax.devices()``."""
+    return jax.make_mesh(tuple(shape), tuple(axes),
+                         axis_types=(AxisType.Auto,) * len(shape),
+                         devices=devices)
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     """TPU v5e: 16x16 = 256 chips per pod; 2 pods = 512 chips multi-pod."""
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return auto_mesh(shape, axes)
 
 
 def make_host_mesh(model_parallel: int = 1):
     """Small mesh over whatever devices exist (CPU tests / examples)."""
     n = len(jax.devices())
     assert n % model_parallel == 0
-    return jax.make_mesh((n // model_parallel, model_parallel),
-                         ("data", "model"))
+    return auto_mesh((n // model_parallel, model_parallel),
+                     ("data", "model"))
 
 
 def make_zoo_mesh(n_workers: int = 0, model_parallel: int = 0):
@@ -38,7 +53,7 @@ def make_zoo_mesh(n_workers: int = 0, model_parallel: int = 0):
         raise ValueError(
             f"make_zoo_mesh: {n_workers} workers x {model_parallel} model "
             f"shards != {n} local devices")
-    return jax.make_mesh((n_workers, model_parallel), ("data", "model"))
+    return auto_mesh((n_workers, model_parallel), ("data", "model"))
 
 
 def worker_axes(mesh) -> tuple:

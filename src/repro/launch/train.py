@@ -1,10 +1,14 @@
-"""Runnable trainer (example-scale on CPU; production mesh on TPU).
+"""Runnable trainer over the local devices (CPU host or TPU chips).
 
   PYTHONPATH=src python -m repro.launch.train --arch gemma2-2b --smoke \
       --steps 20 --agg obcsaa
+  PYTHONPATH=src python -m repro.launch.train --arch mamba2-2.7b \
+      --layers 4 --zoo-train --batch 1 --seq 2048 --cs-chunk 16384 \
+      --cs-measure 32 --cs-topk 8 --optimizer adam --error-feedback
 
-Uses the same step builders as the dry-run; with --smoke the reduced config
-trains on synthetic token streams over a host mesh.
+Uses the same step builders as the dry-run, on a mesh of the devices this
+process sees. --smoke trains the reduced config; without it the model
+keeps its published widths and --layers cuts its depth.
 
 ``--serve`` hands the remaining arguments to the continuous scheduling
 service instead (``repro.serve``, DESIGN.md §15):
@@ -14,6 +18,7 @@ service instead (``repro.serve``, DESIGN.md §15):
 from __future__ import annotations
 
 import argparse
+import dataclasses
 import sys
 import time
 
@@ -24,7 +29,8 @@ import numpy as np
 from repro.configs import TrainConfig, get_config, get_smoke_config
 from repro.data import token_stream
 from repro.launch import steps as steps_lib
-from repro.launch.mesh import make_host_mesh, make_production_mesh, num_workers
+from repro.launch.compile_cache import enable_compile_cache
+from repro.launch.mesh import make_host_mesh
 from repro.models.registry import build_model
 
 
@@ -56,14 +62,19 @@ def run_zoo_train(args, cfg, tcfg, model, mesh):
     per-worker EF residuals — so --ckpt-dir/--resume restore mid-run with
     non-trivial optimizer state bit-for-bit. With --data, every round
     samples a fresh (U, B, S) batch from the memmapped token shards,
-    keyed by the absolute round index (no iterator state to serialize)."""
+    keyed by the absolute round index (no iterator state to serialize).
+
+    Returns one dict per round run (``round``, ``loss``, ``w_norm`` = the
+    master's L2 norm after the round, ``seconds`` until the new carry is
+    ready) for the single-arm run, None for a sweep."""
     zr = steps_lib.make_zoo_train_round(model, tcfg, mesh)
     print(f"zoo-train: D={zr.D:,} n_chunks={zr.n_chunks} "
           f"({zr.n_model} model x {zr.U} workers x {zr.n_local} local), "
           f"optimizer={zr.optimizer_name} ef={zr.error_feedback} "
           f"remat={tcfg.remat_mode}", flush=True)
-    params = model.init(jax.random.PRNGKey(0))
-    master = zr.chunk_params(params)
+    # the params pytree is only the source of the master: dropping it
+    # keeps one copy of the weights on the device, not two
+    master = zr.chunk_params(model.init(jax.random.PRNGKey(0)))
     key = jax.random.PRNGKey(1)
     data_key = jax.random.PRNGKey(2)
     shards = None
@@ -116,6 +127,9 @@ def run_zoo_train(args, cfg, tcfg, model, mesh):
             print(f"saved checkpoint: {path}")
     else:
         state = zr.shard_state(zr.init_state(master))
+        # the carry holds the master now; another reference would keep
+        # the round-0 weights on the device for the whole run
+        del master
         t_start = 0
         if args.resume:
             got = zr.restore_state(args.ckpt_dir)
@@ -123,16 +137,23 @@ def run_zoo_train(args, cfg, tcfg, model, mesh):
                 state, t_start = got
                 print(f"resumed zoo-train at round {t_start}", flush=True)
         batch = None
+        history = []
         for t in range(t_start, args.steps):
             if shards is not None or batch is None:
                 batch = zoo_batch(t)
-            t0 = time.time()
+            t0 = time.perf_counter()
             state, st = zr.round_train(state, batch, t, key,
                                        tcfg.noise_var, tcfg.p_max,
                                        args.lr)
-            print(f"round {t:4d} loss={float(st.loss):.4f} "
-                  f"b_t={float(st.b_t):.4f} ({time.time()-t0:.2f}s)",
-                  flush=True)
+            jax.block_until_ready(state)
+            dt = time.perf_counter() - t0
+            rec = {"round": t, "loss": float(st.loss),
+                   "w_norm": float(jnp.linalg.norm(state.master)),
+                   "seconds": dt}
+            history.append(rec)
+            print(f"round {t:4d} loss={rec['loss']:.4f} "
+                  f"b_t={float(st.b_t):.4f} |w|={rec['w_norm']:.6f} "
+                  f"({dt:.2f}s)", flush=True)
             if args.ckpt_dir and args.ckpt_every \
                     and (t + 1) % args.ckpt_every == 0:
                 zr.save_state(args.ckpt_dir, t + 1, state, t_next=t + 1)
@@ -140,19 +161,25 @@ def run_zoo_train(args, cfg, tcfg, model, mesh):
             path = zr.save_state(args.ckpt_dir, args.steps, state,
                                  t_next=args.steps)
             print(f"saved checkpoint: {path}")
+        return history
 
 
-def main():
-    if "--serve" in sys.argv[1:]:
+def main(argv=None):
+    """Parse ``argv`` (default: ``sys.argv[1:]``) and train. With
+    --zoo-train, returns the per-round history of ``run_zoo_train``."""
+    argv = sys.argv[1:] if argv is None else list(argv)
+    if "--serve" in argv:
         # dispatch to the scheduling-service CLI with the rest of the
         # arguments (repro.serve owns its own parser)
         from repro.serve.cli import main as serve_main
-        argv = [a for a in sys.argv[1:] if a != "--serve"]
-        raise SystemExit(serve_main(argv))
+        raise SystemExit(serve_main([a for a in argv if a != "--serve"]))
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="gemma2-2b")
     ap.add_argument("--smoke", action="store_true",
-                    help="reduced config on the host mesh")
+                    help="reduced widths (the arch's smoke config)")
+    ap.add_argument("--layers", type=int, default=0,
+                    help="keep only the first N layers (a depth cut; "
+                         "every width stays as configured). 0: all")
     ap.add_argument("--steps", type=int, default=10)
     ap.add_argument("--batch", type=int, default=4)
     ap.add_argument("--seq", type=int, default=128)
@@ -202,12 +229,15 @@ def main():
                          "continue; round RNG/schedules index absolute "
                          "steps, so the result matches an uninterrupted "
                          "run (DESIGN.md §14)")
-    args = ap.parse_args()
+    args = ap.parse_args(argv)
     if args.resume and not args.ckpt_dir:
         raise SystemExit("--resume needs --ckpt-dir")
+    enable_compile_cache()
 
     cfg = get_smoke_config(args.arch) if args.smoke else get_config(args.arch)
-    mesh = make_host_mesh() if args.smoke else make_production_mesh()
+    if args.layers:
+        cfg = dataclasses.replace(cfg, num_layers=args.layers)
+    mesh = make_host_mesh()
     tcfg = TrainConfig(aggregation=args.agg, optimizer=args.optimizer,
                        learning_rate=args.lr,
                        error_feedback=args.error_feedback,

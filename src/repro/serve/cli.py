@@ -12,6 +12,7 @@ from typing import Optional, Sequence
 
 import jax
 
+from repro.launch.compile_cache import enable_compile_cache
 from repro.sched.scenario import ScenarioConfig
 from repro.serve.service import init_service, run_ticks, slo_summary
 from repro.serve.state import SERVE_SCHEDULERS, ServeConfig
@@ -45,6 +46,7 @@ def build_parser() -> argparse.ArgumentParser:
 
 def main(argv: Optional[Sequence[str]] = None) -> int:
     args = build_parser().parse_args(argv)
+    enable_compile_cache()
     cfg = ServeConfig(
         scenario=ScenarioConfig(cells=args.cells, workers=args.workers,
                                 model=args.model, corr=args.corr),

@@ -179,6 +179,7 @@ SCRIPT_ELASTIC = textwrap.dedent("""
     import jax, jax.numpy as jnp, numpy as np
     from repro.core.obcsaa import OBCSAAConfig
     from repro.engine import EngineRun, FLConfig, make_arms
+    from repro.launch.mesh import auto_mesh
     from repro.optim import make as make_opt
 
     U, D = 4, 1200
@@ -191,7 +192,7 @@ SCRIPT_ELASTIC = textwrap.dedent("""
     data = {"c": jax.random.normal(jax.random.PRNGKey(3), (U, D))}
     loss = lambda p, d: 0.5 * jnp.sum((p["w"] - d["c"]) ** 2)
     arms = make_arms(cfg, noise_var=[1e-4, 1e-3, 1e-2, 1e-1])
-    mesh = jax.make_mesh((4, 2), ("data", "model"))
+    mesh = auto_mesh((4, 2), ("data", "model"))
     # adam: the checkpoint carries NON-TRIVIAL optimizer moments through
     # the device-layout transitions (DESIGN.md §17)
     mk = lambda: EngineRun(cfg, loss, params0, data, np.ones(U),

@@ -238,6 +238,7 @@ SHARDED_SCRIPT = textwrap.dedent("""
     from repro.core.obcsaa import OBCSAAConfig, reconstruct_chunks
     from repro.decode import DecodeConfig, decode
     from repro.kernels.ref import topk_select_ref
+    from repro.launch.mesh import auto_mesh
 
     n, s, d, k = 16, 256, 512, 64
     phi = make_phi(5, s, d)
@@ -256,7 +257,7 @@ SHARDED_SCRIPT = textwrap.dedent("""
     # chunk-sharded: the chunk dim rides the model axis (DESIGN.md §4/§9).
     # Rows are decoded independently, but per-layout GEMM blocking may
     # round differently — allclose, not bitwise.
-    mesh = jax.make_mesh((1, 8), ("data", "model"))
+    mesh = auto_mesh((1, 8), ("data", "model"))
     ysh = jax.device_put(y, NamedSharding(mesh, P("model", None)))
     with jax.set_mesh(mesh):
         for c, ref in zip(cfgs, refs):

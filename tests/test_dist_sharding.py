@@ -22,6 +22,7 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro.dist import collectives
 from repro.dist.sharding import best_spec, constrain, infer_param_sharding
+from repro.launch.mesh import auto_mesh
 from repro.models.mlp_mnist import init_mlp_mnist
 
 SRC = os.path.join(os.path.dirname(__file__), "..", "src")
@@ -31,7 +32,7 @@ SRC = os.path.join(os.path.dirname(__file__), "..", "src")
 
 @pytest.fixture(scope="module")
 def mesh1():
-    return jax.make_mesh((1, 1), ("data", "model"))
+    return auto_mesh((1, 1), ("data", "model"))
 
 
 def test_best_spec_signature_and_hint_priority(mesh1):
@@ -96,8 +97,9 @@ PROP_SCRIPT = textwrap.dedent("""
     import jax, jax.numpy as jnp, numpy as np
     from jax.sharding import NamedSharding, PartitionSpec as P
     from repro.dist.sharding import best_spec, infer_param_sharding
+    from repro.launch.mesh import auto_mesh
 
-    mesh = jax.make_mesh((4, 2), ("data", "model"))
+    mesh = auto_mesh((4, 2), ("data", "model"))
 
     # divisibility: dim 6 is not divisible by data=4 -> next candidate/repl
     assert best_spec((6, 8), [["data", "model"], None], mesh) == P("model", None)

@@ -18,9 +18,10 @@ SCRIPT = textwrap.dedent("""
     from jax.sharding import PartitionSpec as P
     from repro.core.obcsaa import OBCSAAConfig, simulate_round, shardmap_aggregate
     from repro.core import channel as chan
+    from repro.launch.mesh import auto_mesh
 
     U, D = 4, 2048
-    mesh = jax.make_mesh((4, 2), ("data", "model"))
+    mesh = auto_mesh((4, 2), ("data", "model"))
     cfg = OBCSAAConfig(chunk=1024, measure=256, topk=32, biht_iters=10)
     key = jax.random.PRNGKey(0)
     grads = jax.random.normal(key, (U, D))
@@ -97,12 +98,13 @@ SCRIPT_PACKED_MAC = textwrap.dedent("""
     from jax.sharding import PartitionSpec as P
     from repro.dist.collectives import psum_bits_mac
     from repro.kernels.sign import pack_signs, unpack_signs
+    from repro.launch.mesh import auto_mesh
 
     # 8 workers, one per device: the int32 packed-word MAC psum must equal
     # the f32 einsum superposition of the unpacked +-1 symbols bit for bit
     # (uniform power-of-two scale K*b_t => every partial sum is exact).
     U, n, S = 8, 3, 256
-    mesh = jax.make_mesh((8,), ("data",))
+    mesh = auto_mesh((8,), ("data",))
     key = jax.random.PRNGKey(0)
     proj = jax.random.normal(key, (U, n, S))
     packed = pack_signs(proj)                       # (U, n, S//32) uint32
@@ -136,6 +138,7 @@ SCRIPT_LARGE_D_UPLINK = textwrap.dedent("""
     import jax, jax.numpy as jnp, numpy as np
     from jax.sharding import PartitionSpec as P
     from repro.core.obcsaa import OBCSAAConfig, compress_chunks, shardmap_compress
+    from repro.launch.mesh import auto_mesh
 
     # zoo-scale packed uplink (DESIGN.md §14): full shardmap_compress ->
     # psum_bits_mac pipeline at D = 4.19M on the 8-worker mesh must equal
@@ -146,7 +149,7 @@ SCRIPT_LARGE_D_UPLINK = textwrap.dedent("""
     D = 512 * CH
     cfg = OBCSAAConfig(chunk=CH, measure=S, topk=64, packed=True,
                        spmd_topk=True, bisect_iters=20)
-    mesh = jax.make_mesh((8,), ("data",))
+    mesh = auto_mesh((8,), ("data",))
     grads = jnp.stack([
         0.1 * jax.random.normal(jax.random.fold_in(jax.random.PRNGKey(0), u),
                                 (D,), jnp.float32) for u in range(U)])

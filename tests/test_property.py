@@ -24,9 +24,8 @@ settings.load_profile("ci")
 
 class _StubMesh:
     """Just enough mesh for ``FlatShardLayout.build``: the layout consumes
-    only ``dict(mesh.shape)`` (via ``dist.sharding._axis_sizes`` after
-    ``compat._unwrap``), so property tests can sweep mesh shapes without
-    allocating devices."""
+    only ``dict(mesh.shape)`` (via ``dist.sharding._axis_sizes``), so
+    property tests can sweep mesh shapes without allocating devices."""
 
     def __init__(self, **axes):
         self.shape = dict(axes)

@@ -90,9 +90,15 @@ SCRIPT_PARITY = textwrap.dedent("""
     os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
     import jax, jax.numpy as jnp, numpy as np
     from repro.core.obcsaa import OBCSAAConfig
+    from repro.engine import parity
     from repro.engine.zoo import build_zoo_round
+    from repro.launch.mesh import auto_mesh
 
-    mesh = jax.make_mesh((4, 2), ("data", "model"))
+    def close(got, want, tag):
+        parity.assert_reports([parity.compare_leaf(
+            tag, got, want, parity.TOLERANCES["master"], base=chunked)])
+
+    mesh = auto_mesh((4, 2), ("data", "model"))
     ob = OBCSAAConfig(chunk=256, measure=64, topk=16, biht_iters=3,
                       recon_alg="iht", spmd_topk=True, packed=True,
                       bisect_iters=16)
@@ -107,8 +113,9 @@ SCRIPT_PARITY = textwrap.dedent("""
     # surrogate-gradient round (the >=1B bench path)
     p2, st = zr.round_gen(psh, 0, key, 1e-4, 10.0, 0.1)
     r2, rst = zr.reference_round(chunked, 0, key, 1e-4, 10.0, 0.1)
-    assert np.array_equal(np.asarray(p2), np.asarray(r2)), "gen round"
-    assert np.array_equal(np.asarray(st.ghat_norm), np.asarray(rst.ghat_norm))
+    close(p2, r2, "gen round")
+    np.testing.assert_allclose(np.asarray(st.ghat_norm),
+                               np.asarray(rst.ghat_norm), rtol=1e-5)
     assert all(np.isfinite(np.asarray(x)).all() for x in st.budget)
 
     # real-gradient round (the zoo smoke-tier path), U = 4 workers
@@ -118,15 +125,15 @@ SCRIPT_PARITY = textwrap.dedent("""
     gref = jnp.pad(grads, ((0, 0), (0, zr.D_pad - D))).reshape(
         zr.U, zr.n_chunks, ob.chunk)
     r3, _ = zr.reference_round(chunked, 1, key, 1e-4, 10.0, 0.1, grads=gref)
-    assert np.array_equal(np.asarray(p3), np.asarray(r3)), "grads round"
+    close(p3, r3, "grads round")
 
-    # 3 chained rounds stay on-sharding and stay bitwise
+    # 3 chained rounds stay on-sharding and within tolerance
     p4, stats = zr.run_rounds(psh, 3, key=key, noise_var=1e-4, p_max=10.0,
                               lr=0.1)
     rc = chunked
     for t in range(3):
         rc, _ = zr.reference_round(rc, t, key, 1e-4, 10.0, 0.1)
-    assert np.array_equal(np.asarray(p4), np.asarray(rc)), "3-round chain"
+    close(p4, rc, "3-round chain")
     assert len(stats) == 3
     print("OK")
 """)
@@ -134,9 +141,10 @@ SCRIPT_PARITY = textwrap.dedent("""
 
 @pytest.mark.slow
 def test_zoo_sharded_round_bitwise_parity_8dev():
-    """shard_map'd zoo round on a 4 workers x 2 model shards mesh ==
-    single-device reference, bit for bit (packed int32 uplink + shared
-    full-noise draw; DESIGN.md §14)."""
+    """shard_map'd zoo round on a 4 workers x 2 model shards mesh vs the
+    single-device reference (packed int32 uplink + shared full-noise
+    draw; DESIGN.md §14), within the parity tolerances of
+    engine/parity.py."""
     env = dict(os.environ)
     env["PYTHONPATH"] = SRC
     env["JAX_PLATFORMS"] = "cpu"

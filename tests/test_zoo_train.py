@@ -233,10 +233,12 @@ SCRIPT_TRAIN_PARITY = textwrap.dedent("""
     import jax, jax.numpy as jnp, numpy as np
     from repro.configs import get_smoke_config
     from repro.core.obcsaa import OBCSAAConfig
+    from repro.engine import parity
     from repro.engine.zoo_train import build_zoo_train_round
     from repro.models.registry import build_model
+    from repro.launch.mesh import auto_mesh
 
-    mesh = jax.make_mesh((4, 2), ("data", "model"))
+    mesh = auto_mesh((4, 2), ("data", "model"))
     ob = OBCSAAConfig(chunk=256, measure=64, topk=16, biht_iters=3,
                       recon_alg="iht", spmd_topk=True, packed=True,
                       bisect_iters=16)
@@ -259,19 +261,20 @@ SCRIPT_TRAIN_PARITY = textwrap.dedent("""
     assert np.array_equal(np.asarray(g), np.asarray(gr)), "grads"
     assert np.array_equal(np.asarray(losses), np.asarray(lref)), "losses"
 
-    # 3 chained real-gradient rounds stay bitwise vs the jitted oracle
+    # 3 chained real-gradient rounds stay within the parity tolerances
+    # of the jitted oracle (engine/parity.py gives each its reason)
     m, rc = master, chunked
     for t in range(3):
         m, st = zr.round_train(m, batch, t, key, 1e-4, 10.0, 0.05)
         rc, rst = zr.reference_round_train(rc, raw, t, key, 1e-4, 10.0,
                                            0.05)
-        assert np.array_equal(np.asarray(m.master),
-                              np.asarray(rc.master)), t
+        parity.assert_reports(parity.compare_states(m, rc, master0=chunked,
+                                                    tag=f"round {t}"))
         # loss is telemetry, not round state: the mesh computes it as
         # psum/U, the oracle as mean-over-lax.map — different reduction
         # structures, so close-not-bitwise by contract
         np.testing.assert_allclose(float(st.loss), float(rst.loss),
-                                   rtol=1e-5)
+                                   rtol=parity.LOSS_RTOL)
         assert np.isfinite(float(st.loss))
     assert all(np.isfinite(np.asarray(x)).all() for x in st.budget)
 
@@ -285,8 +288,8 @@ SCRIPT_TRAIN_PARITY = textwrap.dedent("""
     ms = zr.shard_masters(stacked)
     m2, _ = zr.run_sweep(ms, batch, arms, 2, key=key)
     r2, _ = zr.reference_sweep(stacked, raw, arms, 2, key=key)
-    assert np.array_equal(np.asarray(m2.master),
-                          np.asarray(r2.master)), "sweep"
+    parity.assert_reports(parity.compare_states(m2, r2, master0=stacked,
+                                                tag="sweep"))
     print("OK")
 """)
 
@@ -297,10 +300,12 @@ SCRIPT_OPT_STATE_PARITY = textwrap.dedent("""
     import jax, jax.numpy as jnp, numpy as np
     from repro.configs import get_smoke_config
     from repro.core.obcsaa import OBCSAAConfig
+    from repro.engine import parity
     from repro.engine.zoo_train import build_zoo_train_round
     from repro.models.registry import build_model
+    from repro.launch.mesh import auto_mesh
 
-    mesh = jax.make_mesh((4, 2), ("data", "model"))
+    mesh = auto_mesh((4, 2), ("data", "model"))
     ob = OBCSAAConfig(chunk=256, measure=64, topk=16, biht_iters=3,
                       recon_alg="iht", spmd_topk=True, packed=True,
                       bisect_iters=16)
@@ -319,8 +324,9 @@ SCRIPT_OPT_STATE_PARITY = textwrap.dedent("""
             assert np.array_equal(np.asarray(x), np.asarray(y)), (tag, i)
 
     # sharded optimizer moments + per-worker EF residuals: a >=3-round
-    # chain on the 4x2 mesh is bitwise vs the jitted oracle on EVERY
-    # carry leaf (master, moments, adam's step counter, residual)
+    # chain on the 4x2 mesh stays within the parity tolerances of the
+    # jitted oracle on EVERY carry leaf (master, moments, residual; adam's
+    # integer step counter exactly)
     for name, kw in (("momentum", dict(beta=0.9)), ("adam", {})):
         zr = build_zoo_train_round(model, mesh, ob, optimizer=name,
                                    opt_kwargs=kw, error_feedback=True)
@@ -332,7 +338,8 @@ SCRIPT_OPT_STATE_PARITY = textwrap.dedent("""
             s, st = zr.round_train(s, batch, t, key, 1e-4, 10.0, 0.05)
             r, rst = zr.reference_round_train(r, raw, t, key, 1e-4,
                                               10.0, 0.05)
-            leaves_equal(s, r, (name, t))
+            parity.assert_reports(parity.compare_states(
+                s, r, master0=chunked, tag=f"{name} round {t}"))
             assert np.isfinite(float(st.loss)), (name, t)
         assert float(np.abs(np.asarray(s.residual)).sum()) > 0, name
         print(name + " chain parity OK", flush=True)
@@ -381,10 +388,11 @@ SCRIPT_OPT_STATE_PARITY = textwrap.dedent("""
 def test_zoo_train_opt_state_ef_parity_8dev():
     """Tentpole gate (DESIGN.md §17): momentum/adam moments as sharded
     (n_chunks, D_c) carries and per-worker EF residuals as the
-    (U, n_chunks, D_c) grads-layout carry stay bitwise vs the jitted
-    single-device oracle over 3-round chains on the 4x2 mesh, and a
-    checkpoint saved mid-chain and mid-sweep (moments + residuals +
-    t_next) resumes bit for bit."""
+    (U, n_chunks, D_c) grads-layout carry stay within the parity
+    tolerances (engine/parity.py) of the jitted single-device oracle over
+    3-round chains on the 4x2 mesh, and a checkpoint saved mid-chain and
+    mid-sweep (moments + residuals + t_next) resumes bit for bit on the
+    same mesh."""
     env = dict(os.environ)
     env["PYTHONPATH"] = SRC
     env["JAX_PLATFORMS"] = "cpu"
@@ -398,9 +406,10 @@ def test_zoo_train_opt_state_ef_parity_8dev():
 
 @pytest.mark.slow
 def test_zoo_train_sharded_bitwise_parity_8dev():
-    """Real backward passes on the 4 workers x 2 model shards mesh ==
-    single-device oracle, bit for bit: raw in-layout gradients, chained
-    rounds, and the multi-arm sweep (DESIGN.md §16)."""
+    """Real backward passes on the 4 workers x 2 model shards mesh vs the
+    single-device oracle: raw in-layout gradients bit for bit (replicated
+    compute, no cross-device float reduction), chained rounds and the
+    multi-arm sweep within the parity tolerances (DESIGN.md §16)."""
     env = dict(os.environ)
     env["PYTHONPATH"] = SRC
     env["JAX_PLATFORMS"] = "cpu"
