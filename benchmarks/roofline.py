@@ -5,38 +5,22 @@
   memory     = HLO_bytes / HBM_bw                (per device)
   collective = wire_bytes / (links x link_bw)    (per device)
 
-Peaks are per chip, keyed by the ``device_kind`` JAX reports; the dry-run
-lowers for the production mesh of ``launch/mesh.py``, which is TPU v5e
-(Google Cloud "TPU v5e": 197 TFLOP/s bf16, 819 GB/s HBM; ~50 GB/s/link
-ICI). A kind with no entry is an error, never another chip's peaks.
+Peaks are per chip, keyed by the ``device_kind`` each dry-run record
+names (the kind of the production mesh it was lowered for, from
+``launch/mesh.py``). TPU v5e (Google Cloud "TPU v5e": 197 TFLOP/s bf16,
+819 GB/s HBM; ~50 GB/s/link ICI, 4 links in a 2D torus) is the only kind;
+a record of another kind, or of none, is an error.
 MODEL_FLOPS = 6·N·D (dense; N_active for MoE) for the useful-compute ratio.
 """
 from __future__ import annotations
 
 import json
 from pathlib import Path
-from typing import NamedTuple
 
 from repro.configs import ASSIGNED_ARCHS, INPUT_SHAPES, get_config
 
-
-class Peaks(NamedTuple):
-    flops: float     # bf16 FLOP/s per chip
-    hbm_bw: float    # B/s per chip
-    link_bw: float   # B/s per ICI link
-    n_links: int     # ICI links per chip
-
-
-PEAKS = {"TPU v5 lite": Peaks(197e12, 819e9, 50e9, 4)}   # v5e, 2D torus
-PRODUCTION_KIND = "TPU v5 lite"   # what make_production_mesh describes
-
-
-def peaks(kind: str) -> Peaks:
-    if kind not in PEAKS:
-        raise ValueError(f"roofline: no peaks for device kind {kind!r} "
-                         f"(known: {sorted(PEAKS)})")
-    return PEAKS[kind]
-
+# device_kind -> (bf16 FLOP/s, HBM B/s, B/s per ICI link, ICI links), per chip
+PEAKS = {"TPU v5 lite": (197e12, 819e9, 50e9, 4)}     # TPU v5e
 
 DRYRUN_DIR = Path(__file__).resolve().parents[1] / "experiments" / "dryrun"
 
@@ -68,6 +52,11 @@ def model_flops(cfg, shape, kind: str) -> float:
 
 def analyze(rec: dict) -> dict:
     from benchmarks.analytic import bytes_per_device, flops_per_device
+    kind = rec.get("device_kind")
+    if kind not in PEAKS:
+        raise ValueError(f"roofline: no peaks for device kind {kind!r} "
+                         f"(known: {sorted(PEAKS)})")
+    peak_flops, hbm_bw, link_bw, n_links = PEAKS[kind]
     cfg = get_config(rec["arch"])
     shape = INPUT_SHAPES[rec["shape"]]
     agg = rec.get("agg") or "mean"
@@ -79,10 +68,9 @@ def analyze(rec: dict) -> dict:
     bytes_dev = bytes_per_device(cfg, shape, n_dev, agg)
     wire = rec["collectives"].get("total_wire_bytes",
                                   rec["collectives"]["total_bytes"])
-    pk = peaks(rec.get("device_kind", PRODUCTION_KIND))
-    t_comp = flops_dev / pk.flops
-    t_mem = bytes_dev / pk.hbm_bw
-    t_coll = wire / (pk.n_links * pk.link_bw)
+    t_comp = flops_dev / peak_flops
+    t_mem = bytes_dev / hbm_bw
+    t_coll = wire / (n_links * link_bw)
     terms = {"compute_s": t_comp, "memory_s": t_mem, "collective_s": t_coll}
     dom = max(terms, key=terms.get)
     mf_dev = model_flops(cfg, shape, shape.kind) / n_dev

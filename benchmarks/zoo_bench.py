@@ -11,7 +11,8 @@ Methodology:
 - On the CPU every measurement runs in a CHILD process so the 8-device
   XLA host flag never leaks into the caller (the bench harness keeps 1
   device). On a TPU the rows run in the calling process, which holds the
-  chip, on a mesh of the local chips.
+  chips; each row's workers x model mesh must match the local chips, and
+  a row whose mesh they cannot make up fails.
 - Default rows are CI-scale: a parity gate (the 16k-element geometry of
   tests/test_zoo.py — the sharded round must stay within the parity
   tolerances of ``repro.engine.parity`` of the single-device reference
@@ -54,17 +55,6 @@ FULL_KEY = "zoo:v1:full"          # surrogate-grads ≥1B row
 TRAIN_KEY = "zoo:v3"              # real-backward CI rows (state-carry API)
 TRAIN_FULL_KEY = "zoo:v2:full"    # real-backward ≥1B row
 
-def _mesh(spec: dict):
-    """The spec's workers x model mesh where the local devices make it up
-    (the 8-device host mesh); on a TPU with another device count, every
-    local chip (``make_zoo_mesh`` defaults)."""
-    import jax
-    from repro.launch.mesh import make_zoo_mesh
-    if spec["workers"] * spec["mp"] == len(jax.devices()):
-        return make_zoo_mesh(spec["workers"], spec["mp"])
-    return make_zoo_mesh()
-
-
 def _peak_fields() -> dict:
     """Peak process RSS (on the host-device mesh it IS the device memory
     bound) and, where the device reports it, its peak bytes in use."""
@@ -80,7 +70,8 @@ def _peak_fields() -> dict:
 
 
 def run_round(spec: dict) -> dict:
-    """One surrogate-gradient zoo row (engine/zoo.py) on ``_mesh(spec)``."""
+    """One surrogate-gradient zoo row (engine/zoo.py) on the spec's
+    workers x model mesh."""
     import time
 
     import jax
@@ -90,8 +81,9 @@ def run_round(spec: dict) -> dict:
     from repro.core.obcsaa import OBCSAAConfig
     from repro.engine import parity
     from repro.engine.zoo import build_zoo_round
+    from repro.launch.mesh import make_zoo_mesh
 
-    mesh = _mesh(spec)
+    mesh = make_zoo_mesh(spec["workers"], spec["mp"])
     if spec.get("arch"):
         from repro.configs import get_config, get_smoke_config
         from repro.models.registry import build_model
@@ -147,9 +139,10 @@ def run_train(spec: dict) -> dict:
     from repro.core.obcsaa import OBCSAAConfig
     from repro.engine import parity
     from repro.engine.zoo_train import build_zoo_train_round
+    from repro.launch.mesh import make_zoo_mesh
     from repro.models.registry import build_model
 
-    mesh = _mesh(spec)
+    mesh = make_zoo_mesh(spec["workers"], spec["mp"])
     cfg = (get_smoke_config if spec["smoke"] else get_config)(spec["arch"])
     model = build_model(cfg)
     ob = OBCSAAConfig(**spec["ob"])

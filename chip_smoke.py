@@ -72,16 +72,17 @@ def _load_repo():
 
 
 class CompileClock:
-    """Seconds XLA spends compiling (persistent-cache reads included) and
-    the persistent-cache hits, from ``jax.monitoring`` events."""
+    """Seconds XLA spends compiling (persistent-cache reads included), one
+    entry per program, and the persistent-cache hits, from
+    ``jax.monitoring`` events."""
 
     def __init__(self):
         import jax
-        self.seconds, self.hits = 0.0, 0
+        self.programs, self.hits = [], 0
 
         def on_duration(event, duration, **_):
             if event == _COMPILE_EVENT:
-                self.seconds += duration
+                self.programs.append(duration)
 
         def on_event(event, **_):
             if event == "/jax/compilation_cache/cache_hits":
@@ -91,10 +92,11 @@ class CompileClock:
         jax.monitoring.register_event_listener(on_event)
 
     def mark(self):
-        return self.seconds, self.hits
+        return len(self.programs), self.hits
 
     def since(self, mark):
-        return self.seconds - mark[0], self.hits - mark[1]
+        """(per-program compile seconds, cache hits) since ``mark``."""
+        return self.programs[mark[0]:], self.hits - mark[1]
 
 
 def require_tpu(count: int):
@@ -119,10 +121,17 @@ def peak_bytes() -> str:
 
 
 def report(phase, clock_mark, clock, **fields):
-    """One smoke-timing line per phase."""
-    secs, hits = clock.since(clock_mark)
+    """One smoke-timing line per phase. JAX writes a program to the
+    persistent cache only when its compile took at least
+    ``jax_persistent_cache_min_compile_time_secs``; the line counts those."""
+    import jax
+    programs, hits = clock.since(clock_mark)
+    cache_min = jax.config.jax_persistent_cache_min_compile_time_secs
     extra = " ".join(f"{k}={v}" for k, v in fields.items())
-    print(f"[smoke timing] {phase}: xla_compile_s={secs:.2f} "
+    print(f"[smoke timing] {phase}: xla_compile_s={sum(programs):.2f} "
+          f"programs={len(programs)} longest_compile_s="
+          f"{max(programs, default=0.0):.2f} programs_over_cache_min"
+          f"({cache_min}s)={sum(t >= cache_min for t in programs)} "
           f"cache_hits={hits} {extra} peak_bytes_in_use(device 0, "
           f"process so far)={peak_bytes()}", flush=True)
 

@@ -9,11 +9,13 @@ gates the 4x2 host-mesh tests and ``chip_smoke.py --four-chips``.
 
 A row is one chunk (the last axis, D_c elements). A row matches when every
 element satisfies ``|got - want| <= atol + rtol * |want|`` with
-``atol = atol_frac * max|want|`` over the whole leaf. A leaf passes when at
-least ``min_rows`` of its rows match.
+``atol = atol_frac * max|want|`` over the whole leaf; a NaN on either side
+never matches. A leaf passes when at least ``min_rows`` of its rows match
+and its max|diff| and relative L2 difference are finite.
 """
 from __future__ import annotations
 
+import math
 from typing import NamedTuple
 
 import jax
@@ -30,21 +32,30 @@ class Tolerance(NamedTuple):
 # The sharded program and the reference sum the same f32 terms in another
 # order (psum over devices vs a local sum, fusion chosen per shape), which
 # moves final ulps: ~1e-7 relative per reduction, compounded over the
-# decode's iterations and a few rounds. 1e-3 leaves four orders of margin.
+# decode's iterations and a few rounds. Read on the 4x2 host-mesh gates:
+# every leaf without a flipped chunk within 2e-7 relative L2, max|diff|
+# 2.4e-7; rtol 1e-3 is over three orders above that.
 _REORDER = ("f32 reductions reordered across devices and fusions; "
             "~1e-7 relative each, compounded over decode iterations")
 # An ulp difference can move a discrete choice: the kappa-th largest
 # magnitude of a chunk (top-kappa), or the sign of a projection within an
 # ulp of zero. Either changes that one chunk's decoded update, by up to lr
-# per element, and no other chunk. A layout or collective error moves every
-# chunk, so requiring 90% of the chunks to match still catches it.
+# per element, and no other chunk. Readings (rows matched, worst leaf):
+# the 4x2 host-mesh gates 0.9999 (a 2-arm sweep's master), every other
+# leaf 1.0; the 2x2 chip round 0.9998 for master and moments, 0.9840 for
+# the EF residual, whose top-kappa of a corrected gradient ties more often.
+# A fault in one worker or one model shard moves 1/U or 1/n_model of the
+# rows, a layout error every row; the limits sit between the readings and
+# those shares: master and moments 0.99, so a fault on an axis of up to 100
+# fails; the residual 0.97, an axis of up to 33.
 _FLIPS = ("a near-tie in a chunk's top-kappa or a projection within an ulp "
-          "of zero flips that chunk alone; a layout error moves every chunk")
+          "of zero flips that chunk alone; a fault in one worker or model "
+          "shard moves 1/U or 1/n_model of the chunks")
 
 TOLERANCES = {
-    "master": Tolerance(1e-3, 1e-4, 0.9, f"{_REORDER}; {_FLIPS}"),
-    "moment": Tolerance(1e-3, 1e-4, 0.9, f"{_REORDER}; {_FLIPS}"),
-    "residual": Tolerance(1e-3, 1e-4, 0.9,
+    "master": Tolerance(1e-3, 1e-4, 0.99, f"{_REORDER}; {_FLIPS}"),
+    "moment": Tolerance(1e-3, 1e-4, 0.99, f"{_REORDER}; {_FLIPS}"),
+    "residual": Tolerance(1e-3, 1e-4, 0.97,
                           "residual = corrected grad - its top-kappa: "
                           f"{_FLIPS}"),
 }
@@ -75,7 +86,9 @@ def _leaf_stats(got, want, base, rtol, atol_frac):
     diff = jnp.abs(got - want)
     scale = jnp.abs(want)
     atol = atol_frac * jnp.max(scale, initial=0.0)
-    bad = diff > atol + rtol * scale
+    # written so that a NaN on either side is bad: every comparison with
+    # NaN is False
+    bad = ~(diff <= atol + rtol * scale)
     rows = bad.reshape(-1, bad.shape[-1]) if bad.ndim else bad.reshape(1, 1)
     return (jnp.max(diff, initial=0.0), jnp.linalg.norm(diff),
             jnp.linalg.norm(want), 1.0 - jnp.mean(jnp.any(rows, axis=-1)))
@@ -100,7 +113,9 @@ def compare_leaf(name, got, want, tol: Tolerance, *, base=None) -> LeafReport:
     max_abs, n_diff, n_want, matched = map(float, _leaf_stats(
         got, want, base, tol.rtol, tol.atol_frac))
     rel = n_diff / n_want if n_want else max_abs
-    return LeafReport(name, max_abs, rel, matched, matched >= tol.min_rows)
+    ok = (matched >= tol.min_rows and math.isfinite(max_abs)
+          and math.isfinite(rel))
+    return LeafReport(name, max_abs, rel, matched, ok)
 
 
 def compare_states(got, want, *, master0=None, tag: str = ""):

@@ -27,7 +27,8 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro.configs import ASSIGNED_ARCHS, INPUT_SHAPES, TrainConfig, get_config
 from repro.dist.sharding import best_spec
-from repro.launch.mesh import make_production_mesh, num_workers
+from repro.launch.mesh import (PRODUCTION_DEVICE_KIND, make_production_mesh,
+                               num_workers)
 from repro.launch import steps as steps_lib
 from repro.models.registry import build_model
 
@@ -236,6 +237,7 @@ def lower_combo(arch: str, shape_name: str, *, multi_pod: bool,
         "variant": variant,
         "arch": arch, "shape": shape_name,
         "mesh": "2x16x16" if multi_pod else "16x16",
+        "device_kind": PRODUCTION_DEVICE_KIND,
         "agg": agg if shape.kind == "train" else None,
         "n_devices": n_dev,
         "lower_s": round(t_lower, 1), "compile_s": round(t_compile, 1),

@@ -23,6 +23,10 @@ def auto_mesh(shape, axes, *, devices=None):
                          devices=devices)
 
 
+# the device_kind JAX reports for the chips of the production mesh
+PRODUCTION_DEVICE_KIND = "TPU v5 lite"     # TPU v5e
+
+
 def make_production_mesh(*, multi_pod: bool = False):
     """TPU v5e: 16x16 = 256 chips per pod; 2 pods = 512 chips multi-pod."""
     shape = (2, 16, 16) if multi_pod else (16, 16)
