@@ -25,6 +25,7 @@ from typing import Optional, Tuple
 import jax
 import jax.numpy as jnp
 
+from repro import obs
 from repro.core import channel as chan
 from repro.core.measurement import make_phi
 from repro.core.quantize import PACK, pack_signs, sign_pm1, unpack_signs
@@ -176,45 +177,50 @@ def simulate_round(cfg: OBCSAAConfig, grads_flat: jnp.ndarray,
                    k_weights: jnp.ndarray, beta: jnp.ndarray, b_t,
                    h: jnp.ndarray, key, decode_x0=None, noise_var=None,
                    presparsified: bool = False) -> Tuple[jnp.ndarray, dict]:
-    """grads_flat: (U, D). Returns (g_hat (D,), diagnostics).
+    """grads_flat: (U, D). Returns (g_hat (D,), diag).
 
     Implements eq. (6)-(14) with perfect channel inversion: the received
     aggregate is Σ_i K_i b_t β_i C(g_i) + z (eq. 12). ``decode_x0`` warm-
     starts the decoder (eq. 43) with the previous round's raw estimate;
-    ``diag["decode_xhat"]`` carries this round's raw estimate back out so
-    the FL loop can thread the state (DESIGN.md §9). ``noise_var``
-    optionally overrides ``cfg.noise_var`` with a traced value — the FL
-    engine's SNR arms axis (DESIGN.md §11) sweeps it without retracing.
-    ``presparsified=True`` marks ``grads_flat`` as already top-κ sparse
-    per chunk (the engine's fused EF path; see ``compress_chunks``)."""
+    ``diag["decode_xhat"]``, diag's one key, carries this round's raw
+    estimate back out so the FL loop can thread the state (DESIGN.md §9).
+    ``noise_var`` optionally overrides ``cfg.noise_var`` with a traced
+    value — the FL engine's SNR arms axis (DESIGN.md §11) sweeps it
+    without retracing. ``presparsified=True`` marks ``grads_flat`` as
+    already top-κ sparse per chunk (the engine's fused EF path; see
+    ``compress_chunks``). The compression runs under the ``repro.codec``
+    phase scope, the MAC, noise and decode under ``repro.mac_decode``
+    (``repro.obs``)."""
     U, D = grads_flat.shape
     pad = (-D) % cfg.chunk
-    gpad = jnp.pad(grads_flat, ((0, 0), (0, pad)))
-    phi = cfg.phi()
-    signs, mags = jax.vmap(
-        lambda g: compress_chunks(cfg, g, phi,
-                                  presparsified=presparsified))(gpad)
-    # MAC superposition (eq. 12). The packed codec unpacks to the exact
-    # ±1 floats the f32 path produced (shared sign predicate, DESIGN.md
-    # §13), so the identical einsum keeps the two paths bit-for-bit equal;
-    # the wire-level int32 bit-count MAC lives in the shard-mapped path
-    # (collectives.psum_bits_mac).
-    symbols = unpack_signs(signs) if cfg.packed else signs
-    w = k_weights * beta * b_t                      # (U,)
-    y = jnp.einsum("u,ucs->cs", w.astype(symbols.dtype), symbols)
-    nv = cfg.noise_var if noise_var is None else noise_var
-    noise = chan.draw_noise(key, y.shape, nv)
-    y = y + noise                                   # eq. (12)
-    denom = jnp.maximum(jnp.sum(k_weights * beta) * b_t, 1e-12)
-    y = y / denom                                   # eq. (13)
-    mbar = jnp.einsum("u,uc->c", (k_weights * beta).astype(mags.dtype),
-                      mags) / jnp.maximum(jnp.sum(k_weights * beta), 1e-12)
-    ghat, xraw = reconstruct_chunks(
-        cfg, y, mbar if cfg.magnitude_tracking else None, phi,
-        x0=decode_x0, return_raw=True)
-    diag = {"denom": denom, "mbar_mean": jnp.mean(mbar),
-            "y_rms": jnp.sqrt(jnp.mean(y ** 2)), "decode_xhat": xraw}
-    return ghat[:D], diag
+    with obs.phase("codec"):
+        gpad = jnp.pad(grads_flat, ((0, 0), (0, pad)))
+        phi = cfg.phi()
+        signs, mags = jax.vmap(
+            lambda g: compress_chunks(cfg, g, phi,
+                                      presparsified=presparsified))(gpad)
+    with obs.phase("mac_decode"):
+        # MAC superposition (eq. 12). The packed codec unpacks to the
+        # exact ±1 floats the f32 path produced (shared sign predicate,
+        # DESIGN.md §13), so the identical einsum keeps the two paths
+        # bit-for-bit equal; the wire-level int32 bit-count MAC lives in
+        # the shard-mapped path (collectives.psum_bits_mac).
+        symbols = unpack_signs(signs) if cfg.packed else signs
+        w = k_weights * beta * b_t                      # (U,)
+        y = jnp.einsum("u,ucs->cs", w.astype(symbols.dtype), symbols)
+        nv = cfg.noise_var if noise_var is None else noise_var
+        noise = chan.draw_noise(key, y.shape, nv)
+        y = y + noise                                   # eq. (12)
+        denom = jnp.maximum(jnp.sum(k_weights * beta) * b_t, 1e-12)
+        y = y / denom                                   # eq. (13)
+        mbar = jnp.einsum("u,uc->c",
+                          (k_weights * beta).astype(mags.dtype), mags
+                          ) / jnp.maximum(jnp.sum(k_weights * beta), 1e-12)
+        ghat, xraw = reconstruct_chunks(
+            cfg, y, mbar if cfg.magnitude_tracking else None, phi,
+            x0=decode_x0, return_raw=True)
+        ghat = ghat[:D]
+    return ghat, {"decode_xhat": xraw}
 
 
 # --- distributed mode (inside shard_map over worker axes) -------------------------

@@ -31,6 +31,7 @@ from typing import Callable, NamedTuple
 import jax
 import jax.numpy as jnp
 
+from repro import obs
 from repro.core import channel as chan
 from repro.core.obcsaa import simulate_round
 from repro.core.sparsify import (flatten_pytree, topk_sparsify,
@@ -208,11 +209,13 @@ def build_engine(cfg: FLConfig, loss_fn: Callable, opt, D: int, U: int,
         stored in the carry for the next round's warm start (must be an
         ``AdmmDuals`` whenever ``sched_warm_duals`` built the carry with
         one — the scan structure is fixed per build)."""
-        grads = stacked_grads(loss_fn, state.params, worker_data)
+        with obs.phase("grad"):
+            grads = stacked_grads(loss_fn, state.params, worker_data)
         residual = state.residual
         presparse = False
         if ef:
-            grads, residual, sparse = ef_split(grads, residual)
+            with obs.phase("codec"):
+                grads, residual, sparse = ef_split(grads, residual)
         dense = grads          # probe target: pre-compression gradients
         if ef and cfg.aggregator == "obcsaa":
             # fused EF: the residual split's sparse_κ IS what obcsaa
@@ -271,15 +274,16 @@ def build_engine(cfg: FLConfig, loss_fn: Callable, opt, D: int, U: int,
     def full_round(state: EngineState, arm: Arms, worker_data, k_weights,
                    t):
         """The scan body: fade draw + P2 + the full round update."""
-        k_t = jax.random.fold_in(arm.key, t)
-        h, fade = fade_step(state.fade, jax.random.fold_in(k_t, 0))
-        if cfg.aggregator == "perfect":
-            beta = jnp.ones((U,), jnp.float32)
-            b_t = jnp.float32(1.0)
-            duals = None
-        else:
-            beta, b_t, duals = schedule(h, k_weights, arm.noise_var,
-                                        arm.p_max, state.sched_duals)
+        with obs.phase("schedule"):
+            k_t = jax.random.fold_in(arm.key, t)
+            h, fade = fade_step(state.fade, jax.random.fold_in(k_t, 0))
+            if cfg.aggregator == "perfect":
+                beta = jnp.ones((U,), jnp.float32)
+                b_t = jnp.float32(1.0)
+                duals = None
+            else:
+                beta, b_t, duals = schedule(h, k_weights, arm.noise_var,
+                                            arm.p_max, state.sched_duals)
         return round_given_schedule(state, arm, worker_data, k_weights, t,
                                     h, fade, beta, b_t, duals)
 

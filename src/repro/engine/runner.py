@@ -6,7 +6,10 @@ one jitted device call advances ``eval_every`` rounds (carry donated, so
 params/opt/EF/warm-start buffers are reused in place), then the host
 streams metrics (eval_fn, per-round scheduling stats) and launches the
 next chunk. Chunk lengths take at most three distinct values (1,
-``eval_every``, tail), so the jit cache stays bounded.
+``eval_every``, tail), so the jit cache stays bounded. The host loop's
+steps are ``repro.obs`` spans (``init``, ``dispatch``, ``fetch``,
+``eval``), and each stats or eval copy to the host counts in
+``obs.host_copies``.
 
 ``run_sweep`` vmaps the same chunk over an ``Arms`` pytree: A experiment
 arms (seeds × SNR × P^Max × lr) advance in ONE compiled program per
@@ -21,7 +24,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro import checkpoint
+from repro import checkpoint, obs
 from repro.core.sparsify import flatten_pytree
 from repro.engine.core import EngineFns, build_engine
 from repro.engine.state import Arms, SweepCheckpoint, make_arms, single_arm
@@ -180,8 +183,9 @@ class EngineRun:
         ckpt_dir = ckpt_dir if ckpt_dir is not None else cfg.ckpt_dir
         resume = cfg.ckpt_resume if resume is None else resume
         A = int(arms.noise_var.shape[0])
-        state = jax.vmap(lambda a: self.fns.init_state(self._params0, a)
-                         )(arms)
+        with obs.span("init"):
+            state = jax.vmap(
+                lambda a: self.fns.init_state(self._params0, a))(arms)
         t_start = 0
         if resume:
             if not ckpt_dir:
@@ -206,18 +210,23 @@ class EngineRun:
                     f"boundary for rounds={rounds}, eval_every={eval_every} "
                     f"— resume must use the cadence the sweep was saved "
                     f"with (boundary before it: t0={t0})")
-            state, stats = self.run_chunk(state, arms, t0, n, vmapped=True)
+            with obs.span("dispatch"):
+                state, stats = self.run_chunk(state, arms, t0, n,
+                                              vmapped=True)
             # stats leaves: (A, n) -> per-round trajectory slabs
-            n_sched.append(np.asarray(stats.n_scheduled))
-            b_ts.append(np.asarray(stats.b_t))
-            if stats.budget is not None:
-                budgets.append(tuple(np.asarray(x) for x in stats.budget))
-            if stats.agg_err is not None:
-                errs.append(np.asarray(stats.agg_err))
+            with obs.span("fetch"):
+                n_sched.append(obs.fetch(stats.n_scheduled))
+                b_ts.append(obs.fetch(stats.b_t))
+                if stats.budget is not None:
+                    budgets.append(tuple(obs.fetch(x)
+                                         for x in stats.budget))
+                if stats.agg_err is not None:
+                    errs.append(obs.fetch(stats.agg_err))
             if eval_v is not None:
-                loss, acc = eval_v(state.params)
-                losses.append(np.asarray(loss))
-                accs.append(np.asarray(acc))
+                with obs.span("eval"):
+                    loss, acc = eval_v(state.params)
+                    losses.append(obs.fetch(loss))
+                    accs.append(obs.fetch(acc))
                 eval_ts.append(t0 + n - 1)
             if ckpt_dir:
                 checkpoint.save(ckpt_dir, t0 + n, SweepCheckpoint(

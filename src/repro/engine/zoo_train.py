@@ -71,6 +71,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import NamedSharding, PartitionSpec as P
 
+from repro import obs
 from repro.core.obcsaa import OBCSAAConfig, compress_chunks
 from repro.core.sparsify import topk_sparsify, topk_sparsify_bisect
 from repro.dist import collectives as coll
@@ -466,21 +467,27 @@ class ZooTrainRound(ZooRound):
         def body_core(pl, ol, res_u, bl, beta, b_t, noise_key, noise_var,
                       lr):
             """One device's round: backward → (EF-corrected) compress →
-            MAC/decode → optimizer update on the local master block.
-            ``res_u``: this worker's (n_half, D_c) residual rows, or
-            None without EF."""
+            MAC/decode → optimizer update on the local master block, each
+            under its ``repro.obs`` phase scope. ``res_u``: this worker's
+            (n_half, D_c) residual rows, or None without EF."""
             widx = coll.axis_index(waxes)
             half0 = model_idx() * n_half
             batch_u = jax.tree_util.tree_map(lambda x: x[0], bl)
-            loss, g_sect = self._local_loss_and_grads(pl, batch_u)
-            if res_u is None:
-                signs, mags = self._compress_blocks(g_sect)
-                res2 = None
-            else:
-                signs, mags, res2 = self._compress_blocks_ef(g_sect, res_u)
-            ghat, gn2 = self._mac_decode(signs, mags, beta, b_t, noise_key,
-                                         noise_var, widx, half0, None)
-            pl2, ol2 = self._opt_update_blocks(ghat, ol, pl, lr)
+            with obs.phase("grad"):
+                loss, g_sect = self._local_loss_and_grads(pl, batch_u)
+            with obs.phase("codec"):
+                if res_u is None:
+                    signs, mags = self._compress_blocks(g_sect)
+                    res2 = None
+                else:
+                    signs, mags, res2 = self._compress_blocks_ef(g_sect,
+                                                                 res_u)
+            with obs.phase("mac_decode"):
+                ghat, gn2 = self._mac_decode(signs, mags, beta, b_t,
+                                             noise_key, noise_var, widx,
+                                             half0, None)
+            with obs.phase("optim"):
+                pl2, ol2 = self._opt_update_blocks(ghat, ol, pl, lr)
             loss_mean = coll.psum(loss, waxes) / jnp.float32(self.U)
             return pl2, ol2, res2, gn2, loss_mean
 

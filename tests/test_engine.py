@@ -292,6 +292,23 @@ def test_sched_trajectory_dense_every_round(task):
     assert [l.round for l in tr.logs] == [0, 4, 8, 12, 14]
 
 
+def test_sweep_counts_ten_host_copies_a_chunk(task):
+    """``run_sweep`` copies 10 arrays to the host at each chunk boundary
+    with the budget on and an eval: n_scheduled, b_t, the 6 ``ErrorBudget``
+    fields, the eval's loss and accuracy; ``obs.host_copies`` counts each."""
+    from repro import obs
+    from repro.engine.runner import chunk_spans
+    wd, params0, loss_fn, eval_fn, _ = task
+    cfg = _cfg(rounds=5, eval_every=2)
+    run = EngineRun(cfg, loss_fn, params0, wd, np.full(U, 16.0),
+                    eval_fn=eval_fn)
+    before = obs.host_copies
+    out = run.run_sweep(make_arms(cfg, seeds=[0]))
+    chunks = len(chunk_spans(5, 2))
+    assert chunks == 3 and "budget" in out and "loss" in out
+    assert obs.host_copies - before == chunks * 10
+
+
 # --- error feedback ---------------------------------------------------------------
 
 def test_error_feedback_improves_final_nmse(task):
