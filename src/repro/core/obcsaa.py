@@ -61,10 +61,12 @@ class OBCSAAConfig:
     # full chunk array at production scale. The distributed train step turns
     # this on; the single-host simulation keeps exact sort-based top-k.
     spmd_topk: bool = False
-    # Threshold-bisection budget for the spmd path (selection resolution
+    # Threshold-bisection cap for the spmd path (selection resolution
     # max·2^-iters; 40 over-resolves f32 — the engine bench runs 20 with a
-    # selection-parity check, DESIGN.md §11). Applies to compression,
-    # error-feedback splits and the decoder's hard threshold.
+    # selection-parity check, DESIGN.md §11). The search stops once every
+    # row's top-κ set is settled, output bitwise that of `bisect_iters`
+    # passes (one scalar all-reduce every two passes under GSPMD). Applies to
+    # compression, error-feedback splits and the decoder's hard threshold.
     bisect_iters: int = 40
     use_kernels: bool = False    # Pallas kernels (interpret on CPU)
     # Packed 1-bit codec (DESIGN.md §13): compress emits uint32 words (32

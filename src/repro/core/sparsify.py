@@ -27,6 +27,54 @@ def topk_sparsify(g: jnp.ndarray, k: int):
     return g * mask, mask
 
 
+def _bisect_threshold(a: jnp.ndarray, k: int, iters: int):
+    """Row thresholds of the top-k bisection over ``a = |g|`` (f32).
+
+    Each pass halves ``[lo, hi]`` at ``mid``: ``lo`` moves up while more
+    than k entries are ``>= mid``, else ``hi`` moves down; ``cnt_hi``
+    counts the entries ``>= hi``. A row is settled once ``cnt_hi >= k``
+    (every later ``hi`` then selects the same set, its top-k) or once
+    ``mid`` rounds to ``lo`` or ``hi`` (a fixed point, e.g. an all-zero
+    row); later passes leave its mask as it is. The loop stops when every
+    row is settled, ``iters`` passes at most, so the result is bitwise
+    that of ``iters`` passes. Rows with ``0 < nnz < k`` never settle and
+    take all ``iters``.
+
+    The stop test runs once every two passes, computed in the loop body:
+    on a TPU v5e, waiting on it after every pass cost more than the pass
+    (PERF.md §6). An odd ``iters`` takes its odd pass before the loop.
+
+    Returns ``(lo, hi, cnt_hi, passes)``, each threshold without the row
+    axis; ``passes`` counts the passes made."""
+    hi = jnp.max(a, axis=-1)
+    lo = jnp.zeros_like(hi)
+    cnt_hi = jnp.sum((a >= hi[..., None]).astype(jnp.int32), axis=-1)
+
+    def halve(lo, hi, cnt_hi):
+        mid = 0.5 * (lo + hi)
+        cnt = jnp.sum((a >= mid[..., None]).astype(jnp.int32), axis=-1)
+        up = cnt > k
+        return (jnp.where(up, mid, lo), jnp.where(up, hi, mid),
+                jnp.where(up, cnt_hi, cnt))
+
+    def unsettled(lo, hi, cnt_hi):
+        mid = 0.5 * (lo + hi)
+        return jnp.any((cnt_hi < k) & (lo < mid) & (mid < hi))
+
+    def body(state):
+        i, lo, hi, cnt_hi, _ = state
+        lo, hi, cnt_hi = halve(*halve(lo, hi, cnt_hi))
+        return i + 2, lo, hi, cnt_hi, unsettled(lo, hi, cnt_hi)
+
+    i = iters % 2
+    if i:
+        lo, hi, cnt_hi = halve(lo, hi, cnt_hi)
+    passes, lo, hi, cnt_hi, _ = jax.lax.while_loop(
+        lambda s: s[4] & (s[0] < iters), body,
+        (jnp.int32(i), lo, hi, cnt_hi, unsettled(lo, hi, cnt_hi)))
+    return lo, hi, cnt_hi, passes
+
+
 def topk_sparsify_bisect(g: jnp.ndarray, k: int, iters: int = 40):
     """SPMD-friendly top-k: bisection on the magnitude threshold.
 
@@ -36,23 +84,18 @@ def topk_sparsify_bisect(g: jnp.ndarray, k: int, iters: int = 40):
     uses only elementwise ops + row reductions, which shard perfectly.
     Exact for rows with distinct magnitudes (ties may admit > k entries —
     measure-zero for float gradients); same algorithm as the Pallas
-    ``topk_select`` kernel."""
+    ``topk_select`` kernel.
+
+    The search stops once every row's top-k set is settled
+    (``_bisect_threshold``): ``iters`` is a cap (resolution max·2^-iters),
+    and the output is bitwise that of ``iters`` fixed passes. Under GSPMD
+    with rows sharded, the stop test's ``any`` over rows costs one scalar
+    all-reduce every two passes. The loop has no reverse-mode rule: nothing
+    differentiates through selection."""
     a = jnp.abs(g.astype(jnp.float32))
-    hi = jnp.max(a, axis=-1, keepdims=True)
-    lo = jnp.zeros_like(hi)
-
-    def body(_, lohi):
-        lo, hi = lohi
-        mid = 0.5 * (lo + hi)
-        cnt = jnp.sum((a >= mid).astype(jnp.int32), axis=-1, keepdims=True)
-        lo = jnp.where(cnt > k, mid, lo)
-        hi = jnp.where(cnt > k, hi, mid)
-        return lo, hi
-
-    lo, hi = jax.lax.fori_loop(0, iters, body, (lo, hi))
-    mask = a >= hi
-    cnt_hi = jnp.sum(mask.astype(jnp.int32), axis=-1, keepdims=True)
-    mask = jnp.where(cnt_hi >= k, mask, a >= lo)
+    lo, hi, cnt_hi, _ = _bisect_threshold(a, k, iters)
+    mask = jnp.where((cnt_hi >= k)[..., None], a >= hi[..., None],
+                     a >= lo[..., None])
     return g * mask, mask
 
 

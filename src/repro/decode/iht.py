@@ -52,7 +52,10 @@ def hard_threshold_bisect(x: jnp.ndarray, k: int,
                           iters: int = 40) -> jnp.ndarray:
     """η_κ via magnitude-threshold bisection — the SPMD-partitionable
     variant (``jax.lax.top_k`` lowers to a sort GSPMD cannot shard).
-    ``iters`` is the threshold resolution budget (max·2^-iters)."""
+    ``iters`` caps the passes (resolution max·2^-iters): the search stops
+    once every row's top-κ set is settled, with output bitwise that of
+    ``iters`` passes; under GSPMD the stop test costs one scalar
+    all-reduce every two passes (``core.sparsify.topk_sparsify_bisect``)."""
     from repro.core.sparsify import topk_sparsify_bisect  # lazy: decode
     # never imports repro.core at module scope (core imports decode)
     return topk_sparsify_bisect(x, k, iters=iters)[0]
