@@ -28,7 +28,7 @@ def _attn_layers(cfg: ModelConfig):
     if cfg.family == "ssm":
         return 0, 0, 0
     if cfg.family == "hybrid":
-        n = cfg.num_layers // max(1, cfg.hybrid_attn_every)
+        n = len(cfg.hybrid_layer_ids)
         return n, 0, 0
     L = cfg.num_layers + cfg.num_encoder_layers
     a = cfg.attention

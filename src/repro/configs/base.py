@@ -39,6 +39,7 @@ class AttentionConfig:
     num_kv_heads: int = 8
     head_dim: int = 0               # 0 -> d_model // num_heads
     rope_theta: float = 10_000.0
+    softmax_scale: float = 0.0      # 0 -> head_dim ** -0.5
     # Sliding window: 0 = full attention. For alternating patterns,
     # layer_pattern controls which layers are local.
     window: int = 0
@@ -67,8 +68,13 @@ class ModelConfig:
     # meaning layer i is GLOBAL iff (i % p) == p-1. "" = uniform.
     layer_pattern: str = ""         # e.g. "local:global" period via fields below
     local_global_period: int = 0    # 0 = all layers per attention.window
-    # hybrid (zamba2): shared attention block applied every N mamba layers
-    hybrid_attn_every: int = 0
+    # hybrid (zamba2): before each layer in hybrid_layer_ids, call c runs
+    # shared block c % num_mem_blocks on [h, embedding] (2 d_model wide)
+    # with its own rank-adapter_rank LoRA on the MLP's gate_up and its own
+    # d x d linear, whose output joins h at the layer's mamba norm
+    hybrid_layer_ids: Tuple[int, ...] = ()
+    num_mem_blocks: int = 0
+    adapter_rank: int = 0
     # enc-dec (whisper)
     num_encoder_layers: int = 0
     encoder_seq_len: int = 0        # stub frontend frames (whisper: 1500)
@@ -76,6 +82,7 @@ class ModelConfig:
     num_image_tokens: int = 0
     final_logit_softcap: float = 0.0
     gated_mlp: bool = True          # SwiGLU (3 mats) vs GELU MLP (2 mats)
+    embed_scale: bool = True        # embedding times sqrt(d_model)
     # §Perf: flash-decoding partial-softmax over a length-sharded KV cache
     # (0 = off -> all-gather decode attention). See attention.py.
     decode_sharded_chunks: int = 0
@@ -123,13 +130,14 @@ class ModelConfig:
             nheads = d_in // s.head_dim
             conv_dim = d_in + 2 * s.n_groups * s.d_state
             per_layer += d * (2 * d_in + 2 * s.n_groups * s.d_state + nheads)  # in_proj
-            per_layer += s.conv_width * conv_dim       # conv1d
+            per_layer += (s.conv_width + 1) * conv_dim  # conv1d, bias
             per_layer += nheads * 2                    # A_log, D
             per_layer += nheads                        # dt_bias
             per_layer += d_in * d                      # out_proj
             per_layer += d                             # norm
             per_layer += d_in                          # gated rmsnorm
-        if self.attention is not None and self.family != "ssm":
+        if self.attention is not None and self.family not in ("ssm",
+                                                               "hybrid"):
             a = self.attention
             hd = self.head_dim
             if a.use_mla:
@@ -160,12 +168,15 @@ class ModelConfig:
             per_layer += d                             # ffn norm
         if self.family == "hybrid":
             n += self.num_layers * per_layer
-            # one shared attention + mlp block
+            # shared blocks on [h, embedding], then per call its adapter
+            # and linear
             a = self.attention
-            hd = self.head_dim
-            shared = d * a.num_heads * hd + 2 * d * a.num_kv_heads * hd + a.num_heads * hd * d
-            shared += (3 if self.gated_mlp else 2) * d * self.d_ff + 2 * d
-            n += shared
+            hd, dA, F = self.head_dim, 2 * d, self.d_ff
+            block = (dA * (a.num_heads + 2 * a.num_kv_heads) * hd
+                     + a.num_heads * hd * d + dA + d + 3 * d * F)
+            call = self.adapter_rank * (d + 2 * F) + d * d
+            n += self.num_mem_blocks * block
+            n += len(self.hybrid_layer_ids) * call
         else:
             n += self.num_layers * per_layer
         if self.num_encoder_layers:

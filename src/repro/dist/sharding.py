@@ -27,9 +27,10 @@ from typing import Sequence
 import jax
 from jax.sharding import AxisType, NamedSharding, PartitionSpec as P
 
-# Pytree keys whose subtrees hold layer-stacked leaves: dim 0 is the
+# Pytree keys whose subtrees hold stacked leaves (layers; the hybrid
+# family's shared blocks and per-call weights): dim 0 is the
 # lax.scan axis, not a shardable weight dim.
-STACKED_KEYS = ("layers", "enc_layers")
+STACKED_KEYS = ("layers", "enc_layers", "shared_blocks", "hybrid")
 
 
 def _ambient():

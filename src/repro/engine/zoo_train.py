@@ -23,9 +23,10 @@ The scheme (one ``jax.shard_map`` program over the whole mesh):
 * The forward/backward is *redundant over the model axis*: every device
   in a worker column runs the worker's full loss on the worker's batch,
   resolving weight shards to full per-layer weights one scan step at a
-  time through ``lm_forward``'s ``layer_resolver`` hook (non-stacked
-  leaves — embedding, norms, shared blocks — are resolved once up
-  front). The resolver's collective is ``collectives.replicated_gather``,
+  time through ``lm_forward``'s ``layer_resolver`` hook (the hybrid
+  family's shared blocks and per-call weights likewise, one call at a
+  time; non-stacked leaves — embedding, final norm — are resolved once
+  up front). The resolver's collective is ``collectives.replicated_gather``,
   whose adjoint is a LOCAL slice: replicated compute means replicated
   cotangents, so no cross-device float reduction exists anywhere in the
   backward and the round stays bitwise mesh-invariant. Remat policy

@@ -21,16 +21,26 @@ from repro.models.layers import apply_rope, he_init, rmsnorm, softcap
 
 # --- init ----------------------------------------------------------------------
 
-def init_gqa(key, d_model: int, a: AttentionConfig, dtype=jnp.float32):
+def init_gqa(key, d_model: int, a: AttentionConfig, dtype=jnp.float32,
+             d_out: int = 0):
+    """Projections from ``d_model`` inputs; ``wo`` back to ``d_out``
+    (0: d_model). Zamba2's shared block reads [h, embedding], 2 d wide,
+    and writes d."""
     hd = a.head_dim if a.head_dim else d_model // a.num_heads
+    d_out = d_out or d_model
     ks = jax.random.split(key, 4)
     return {
         "wq": he_init(ks[0], (d_model, a.num_heads, hd), fan_in=d_model, dtype=dtype),
         "wk": he_init(ks[1], (d_model, a.num_kv_heads, hd), fan_in=d_model, dtype=dtype),
         "wv": he_init(ks[2], (d_model, a.num_kv_heads, hd), fan_in=d_model, dtype=dtype),
-        "wo": he_init(ks[3], (a.num_heads, hd, d_model),
+        "wo": he_init(ks[3], (a.num_heads, hd, d_out),
                       fan_in=a.num_heads * hd, dtype=dtype),
     }
+
+
+def softmax_scale(a: AttentionConfig, hd: int) -> float:
+    """The scores' scale: ``a.softmax_scale`` where set, else hd^-0.5."""
+    return a.softmax_scale or 1.0 / math.sqrt(hd)
 
 
 def init_mla(key, d_model: int, a: AttentionConfig, dtype=jnp.float32):
@@ -147,7 +157,7 @@ def gqa_forward(p, x, a: AttentionConfig, *, positions, causal=True,
             k = apply_rope(k, k_pos, a.rope_theta)
     window = a.window if a.window else None
     out = blockwise_attention(
-        q, k, v, positions, k_pos, scale=1.0 / math.sqrt(hd), causal=causal,
+        q, k, v, positions, k_pos, scale=softmax_scale(a, hd), causal=causal,
         window=window, is_global=is_global, cap=a.logit_softcap)
     out = jnp.einsum("bshk,hkd->bsd", out, p["wo"].astype(x.dtype))
     return constrain(out, ("data", None, None)), (k, v)
@@ -223,11 +233,11 @@ def gqa_decode(p, x, a: AttentionConfig, *, cache_k, cache_v, pos,
     window = a.window if a.window else None
     if sharded_cache_chunks and not cross:
         out = decode_attention_sharded(
-            q, k, v, pos, scale=1.0 / math.sqrt(hd), window=window,
+            q, k, v, pos, scale=softmax_scale(a, hd), window=window,
             is_global=is_global, cap=a.logit_softcap,
             n_chunks=sharded_cache_chunks)
     else:
-        out = _block_attend(q, k, v, q_pos, k_pos, scale=1.0 / math.sqrt(hd),
+        out = _block_attend(q, k, v, q_pos, k_pos, scale=softmax_scale(a, hd),
                             causal=causal, window=window, is_global=is_global,
                             cap=a.logit_softcap, kv_valid=kv_valid)
     out = jnp.einsum("bshk,hkd->bsd", out, p["wo"].astype(x.dtype))

@@ -45,6 +45,16 @@ def init_mamba2(key, d_model: int, s: SSMConfig, dtype=jnp.float32):
     return p
 
 
+def gated_rmsnorm(y, z, scale, eps, groups: int = 1):
+    """RMS norm of y * silu(z) over each of ``groups`` equal slices of the
+    last dim, times (1 + scale). One group is the plain ``rmsnorm``."""
+    g = y * jax.nn.silu(z)
+    if groups == 1:
+        return rmsnorm(g, scale, eps)
+    return rmsnorm(g.reshape(g.shape[:-1] + (groups, -1)),
+                   scale.reshape(groups, -1), eps).reshape(g.shape)
+
+
 def _split_proj(zxbcdt, d_inner, n_groups, d_state, n_heads):
     z, xs, B, C, dt = jnp.split(
         zxbcdt,
@@ -128,8 +138,9 @@ def ssd_chunked(x, dt, A, B, C, chunk: int, init_state=None):
 
 
 def mamba2_forward(p, x, s: SSMConfig, *, init_conv=None, init_ssm=None,
-                   eps=1e-6):
-    """x: (B,S,d). Returns (out, (conv_state, ssm_state))."""
+                   eps=1e-6, norm_groups: int = 1):
+    """x: (B,S,d). Returns (out, (conv_state, ssm_state)). The gated norm
+    runs over ``norm_groups`` groups of d_inner."""
     d_model = x.shape[-1]
     d_inner, n_heads, conv_dim = ssm_dims(d_model, s)
     zxbcdt = x @ p["in_proj"].astype(x.dtype)
@@ -155,12 +166,13 @@ def mamba2_forward(p, x, s: SSMConfig, *, init_conv=None, init_ssm=None,
                                Bg, Cg, chunk, init_state=init_ssm)
     y = y + xh * p["D"].astype(y.dtype)[None, None, :, None]
     y = y.reshape(bsz, S, d_inner)
-    y = rmsnorm(y * jax.nn.silu(z), p["gate_norm"], eps)
+    y = gated_rmsnorm(y, z, p["gate_norm"], eps, norm_groups)
     out = y @ p["out_proj"].astype(x.dtype)
     return constrain(out, ("data", None, None)), (conv_state, ssm_state)
 
 
-def mamba2_decode(p, x, s: SSMConfig, *, conv_state, ssm_state, eps=1e-6):
+def mamba2_decode(p, x, s: SSMConfig, *, conv_state, ssm_state, eps=1e-6,
+                  norm_groups: int = 1):
     """Single-token recurrent step. x: (B,1,d).
 
     conv_state: (B, W-1, conv_dim); ssm_state: (B,h,p,n) float32."""
@@ -189,6 +201,6 @@ def mamba2_decode(p, x, s: SSMConfig, *, conv_state, ssm_state, eps=1e-6):
     y = jnp.einsum("bhpn,bhn->bhp", ssm_state, Ch)
     y = y + xh * p["D"].astype(jnp.float32)[None, :, None]
     y = y.reshape(x.shape[0], 1, d_inner).astype(x.dtype)
-    y = rmsnorm(y * jax.nn.silu(z), p["gate_norm"], eps)
+    y = gated_rmsnorm(y, z, p["gate_norm"], eps, norm_groups)
     out = y @ p["out_proj"].astype(x.dtype)
     return out, (conv_state, ssm_state)

@@ -2,8 +2,11 @@
 
 Layers are a ``lax.scan`` over stacked per-layer params (bounded HLO size and
 compile time at 62+ layers), with optional ``jax.checkpoint`` remat in the
-train path. Per-layer structural variation (local/global attention, hybrid
-shared-attention application) is carried by scanned flag arrays.
+train path. Per-layer structural variation (local/global attention) is
+carried by scanned flag arrays. The hybrid family (Zamba2) splits the stack
+at its hybrid layers: runs of plain Mamba layers are scanned, and each
+hybrid layer runs its call of a shared block, then its own Mamba layer
+(``hybrid_segments``).
 
 Decode maintains functional KV/SSM caches stacked over layers.
 """
@@ -16,6 +19,7 @@ from typing import Any, Dict, Optional, Tuple
 import jax
 import jax.numpy as jnp
 
+from repro import obs
 from repro.configs.base import ModelConfig, dtype_of
 from repro.dist.sharding import constrain
 from repro.models import attention as attn
@@ -51,16 +55,60 @@ def _init_layer(key, cfg: ModelConfig, dtype):
     return p
 
 
-def _init_shared_attn_block(key, cfg: ModelConfig, dtype):
-    """Zamba2-style weight-tied attention+MLP block."""
-    d = cfg.d_model
-    ks = jax.random.split(key, 2)
+def _init_shared_block(key, cfg: ModelConfig, dtype):
+    """One Zamba2 shared block, its weights tied over its calls: RMS norm
+    and attention over [h, embedding] (2 d_model wide) back to d_model,
+    RMS norm, gated MLP (``gate_up`` d x 2 d_ff, ``down``)."""
+    d, dA, F = cfg.d_model, 2 * cfg.d_model, cfg.d_ff
+    ks = jax.random.split(key, 3)
     return {
-        "attn_norm": jnp.zeros((d,), dtype),
-        "attn": attn.init_gqa(ks[0], d, cfg.attention, dtype),
+        "attn_norm": jnp.zeros((dA,), dtype),
+        "attn": attn.init_gqa(ks[0], dA, cfg.attention, dtype, d_out=d),
         "ffn_norm": jnp.zeros((d,), dtype),
-        "mlp": init_mlp(ks[1], d, cfg.d_ff, cfg.gated_mlp, dtype),
+        "gate_up": he_init(ks[1], (d, 2 * F), dtype=dtype),
+        "down": he_init(ks[2], (F, d), dtype=dtype),
     }
+
+
+def _init_hybrid_call(key, cfg: ModelConfig, dtype):
+    """What one call of a shared block owns: the LoRA adapter of the
+    block's ``gate_up`` (d x r, r x 2 d_ff) and the d x d ``linear`` after
+    the block."""
+    d, r, F = cfg.d_model, cfg.adapter_rank, cfg.d_ff
+    ks = jax.random.split(key, 3)
+    return {
+        "adapter_in": he_init(ks[0], (d, r), dtype=dtype),
+        "adapter_out": he_init(ks[1], (r, 2 * F), dtype=dtype),
+        "linear": he_init(ks[2], (d, d), dtype=dtype),
+    }
+
+
+def hybrid_segments(cfg: ModelConfig):
+    """The layer stack as ``(start, stop, call)`` runs: plain layers
+    (call None, scanned) and single hybrid layers (call = index into
+    ``hybrid_layer_ids``, which uses shared block call % num_mem_blocks)."""
+    ids = cfg.hybrid_layer_ids if cfg.family == "hybrid" else ()
+    out, start = [], 0
+    for c, i in enumerate(ids):
+        if i > start:
+            out.append((start, i, None))
+        out.append((i, i + 1, c))
+        start = i + 1
+    if start < cfg.num_layers:
+        out.append((start, cfg.num_layers, None))
+    return out
+
+
+def _rows(tree, start, stop, n):
+    """Rows start:stop of a tree stacked over n (the tree itself when
+    that is all of it)."""
+    if (start, stop) == (0, n):
+        return tree
+    return jax.tree_util.tree_map(lambda a: a[start:stop], tree)
+
+
+def _row(tree, i):
+    return jax.tree_util.tree_map(lambda a: a[i], tree)
 
 
 def layer_flags(cfg: ModelConfig):
@@ -73,12 +121,7 @@ def layer_flags(cfg: ModelConfig):
         is_global = jnp.ones((L,), bool) if (cfg.attention is None
                                              or not cfg.attention.window) \
             else jnp.zeros((L,), bool)
-    if cfg.hybrid_attn_every:
-        apply_attn = (jnp.arange(L) % cfg.hybrid_attn_every) == \
-            (cfg.hybrid_attn_every - 1)
-    else:
-        apply_attn = jnp.zeros((L,), bool)
-    return {"is_global": is_global, "apply_attn": apply_attn}
+    return {"is_global": is_global}
 
 
 def init_lm(key, cfg: ModelConfig):
@@ -95,7 +138,13 @@ def init_lm(key, cfg: ModelConfig):
         params["lm_head"] = he_init(ks[2], (cfg.d_model, cfg.vocab_size),
                                     fan_in=cfg.d_model, dtype=dtype)
     if cfg.family == "hybrid":
-        params["shared_block"] = _init_shared_attn_block(ks[3], cfg, dtype)
+        kb, kc = jax.random.split(ks[3])
+        params["shared_blocks"] = jax.vmap(
+            lambda k: _init_shared_block(k, cfg, dtype))(
+                jax.random.split(kb, cfg.num_mem_blocks))
+        params["hybrid"] = jax.vmap(
+            lambda k: _init_hybrid_call(k, cfg, dtype))(
+                jax.random.split(kc, len(cfg.hybrid_layer_ids)))
     if cfg.family == "vlm":
         # stub projector bias marker (frontend itself is external, DESIGN §8)
         params["img_pos"] = (0.02 * jax.random.normal(
@@ -105,8 +154,18 @@ def init_lm(key, cfg: ModelConfig):
 
 # --- layer application ------------------------------------------------------------
 
-def _apply_layer_full(lp, x, cfg: ModelConfig, flags, positions, shared_block):
-    """Full-sequence (train/prefill) layer. Returns (x, cache_seed, aux)."""
+def gate_norm_groups(cfg: ModelConfig) -> int:
+    """Groups of the Mamba gated norm: Zamba2 normalizes each of its
+    n_groups slices of d_inner; the mamba2 family the whole d_inner (the
+    two agree at the published Mamba-2's ngroups of 1)."""
+    return cfg.ssm.n_groups if cfg.family == "hybrid" else 1
+
+
+def _apply_layer_full(lp, x, cfg: ModelConfig, flags, positions, t=None):
+    """Full-sequence (train/prefill) layer. Returns (x, cache_seed, aux).
+
+    ``t`` (ssm / hybrid): a hybrid call's output, added to the layer's
+    input at its Mamba norm only (the residual carries x alone)."""
     fam = cfg.family
     eps = cfg.norm_eps
     aux = jnp.zeros((), jnp.float32)
@@ -131,33 +190,53 @@ def _apply_layer_full(lp, x, cfg: ModelConfig, flags, positions, shared_block):
             o = mlp(lp["mlp"], h, cfg.gated_mlp)
         x = x + o
     else:  # ssm / hybrid
-        h = rmsnorm(x, lp["ssm_norm"], eps)
-        o, (conv_st, ssm_st) = ssm_lib.mamba2_forward(lp["ssm"], h, cfg.ssm,
-                                                      eps=eps)
-        x = x + o
+        with obs.block("mamba"):
+            h = rmsnorm(x if t is None else x + t, lp["ssm_norm"], eps)
+            o, (conv_st, ssm_st) = ssm_lib.mamba2_forward(
+                lp["ssm"], h, cfg.ssm, eps=eps,
+                norm_groups=gate_norm_groups(cfg))
+            x = x + o
         cache_seed = (conv_st, ssm_st)
-        if fam == "hybrid":
-            def with_attn(x):
-                sb = shared_block
-                h = rmsnorm(x, sb["attn_norm"], eps)
-                o, (k, v) = attn.gqa_forward(sb["attn"], h, cfg.attention,
-                                             positions=positions)
-                x = x + o
-                h = rmsnorm(x, sb["ffn_norm"], eps)
-                x = x + mlp(sb["mlp"], h, cfg.gated_mlp)
-                return x, (k, v)
-
-            def without_attn(x):
-                a = cfg.attention
-                hd = cfg.head_dim
-                B, S = x.shape[0], x.shape[1]
-                z = jnp.zeros((B, S, a.num_kv_heads, hd), x.dtype)
-                return x, (z, z)
-
-            x, (k, v) = jax.lax.cond(flags["apply_attn"], with_attn,
-                                     without_attn, x)
-            cache_seed = cache_seed + (k, v)
     return x, cache_seed, aux
+
+
+def _shared_mlp(blk, call, h, cfg: ModelConfig):
+    """The shared block's gated MLP, with call's LoRA adapter added to
+    ``gate_up``: gelu(gate) * up (exact GELU, Zamba2's ``hidden_act``),
+    then ``down``."""
+    dt = h.dtype
+    gu = (h @ blk["gate_up"].astype(dt)
+          + (h @ call["adapter_in"].astype(dt))
+          @ call["adapter_out"].astype(dt))
+    gate, up = jnp.split(gu, 2, axis=-1)
+    return (jax.nn.gelu(gate, approximate=False) * up) \
+        @ blk["down"].astype(dt)
+
+
+def hybrid_call(blk, call, x, e, cfg: ModelConfig, positions):
+    """One call of a shared block on [x, e] (train/prefill), then call's
+    ``linear``. Returns (t, (k, v)); no residual inside the block."""
+    eps = cfg.norm_eps
+    with obs.block("shared"):
+        h = rmsnorm(jnp.concatenate([x, e], axis=-1), blk["attn_norm"], eps)
+        o, kv = attn.gqa_forward(blk["attn"], h, cfg.attention,
+                                 positions=positions)
+        h = rmsnorm(o, blk["ffn_norm"], eps)
+        t = _shared_mlp(blk, call, h, cfg) @ call["linear"].astype(x.dtype)
+    return t, kv
+
+
+def _hybrid_call_decode(blk, call, x, e, cfg: ModelConfig, k, v, pos):
+    """``hybrid_call`` for one token against call's KV cache. Returns
+    (t, k, v)."""
+    eps = cfg.norm_eps
+    with obs.block("shared"):
+        h = rmsnorm(jnp.concatenate([x, e], axis=-1), blk["attn_norm"], eps)
+        o, k, v = attn.gqa_decode(blk["attn"], h, cfg.attention,
+                                  cache_k=k, cache_v=v, pos=pos)
+        h = rmsnorm(o, blk["ffn_norm"], eps)
+        t = _shared_mlp(blk, call, h, cfg) @ call["linear"].astype(x.dtype)
+    return t, k, v
 
 
 def remat_wrap(body, remat):
@@ -200,7 +279,9 @@ def lm_forward(params, cfg: ModelConfig, tokens, *, image_embeds=None,
     weight shards into full per-layer weights one layer at a time —
     nothing dense at full model size ever exists (DESIGN.md §16)."""
     dtype = dtype_of(cfg)
-    x = embed(params["embedding"], tokens, dtype) * math.sqrt(cfg.d_model)
+    x = embed(params["embedding"], tokens, dtype)
+    if cfg.embed_scale:
+        x = x * math.sqrt(cfg.d_model)
     if cfg.family == "vlm":
         img = (image_embeds.astype(dtype)
                + params["img_pos"].astype(dtype)[None])
@@ -208,7 +289,7 @@ def lm_forward(params, cfg: ModelConfig, tokens, *, image_embeds=None,
     B, S, _ = x.shape
     positions = jnp.arange(S, dtype=jnp.int32)
     flags = layer_flags(cfg)
-    shared_block = params.get("shared_block")
+    resolve = layer_resolver or (lambda lp: lp)
 
     def body(carry, xs):
         x, aux_acc = carry
@@ -218,16 +299,49 @@ def lm_forward(params, cfg: ModelConfig, tokens, *, image_embeds=None,
         # DESIGN.md §16 — inside full-manual shard_map it degrades to a
         # no-op and the body IS already per-device).
         x = constrain(x, ("data", None, None))
-        if layer_resolver is not None:
-            lp = layer_resolver(lp)
-        x, cache_seed, aux = _apply_layer_full(lp, x, cfg, fl, positions,
-                                               shared_block)
+        x, cache_seed, aux = _apply_layer_full(resolve(lp), x, cfg, fl,
+                                               positions)
         ys = cache_seed if collect_cache else None
         return (x, aux_acc + aux), ys
 
+    def hybrid(x, lp, blk, call):
+        # the block and the call's weights are resolved here, inside the
+        # remat boundary, one block at a time like the layers
+        x = constrain(x, ("data", None, None))
+        t, kv = hybrid_call(resolve(blk), resolve(call), x, e, cfg,
+                             positions)
+        x, cache_seed, _ = _apply_layer_full(resolve(lp), x, cfg, None,
+                                             positions, t)
+        return x, cache_seed + kv
+
     body_fn = remat_wrap(body, remat)
-    (x, aux), caches = jax.lax.scan(body_fn, (x, jnp.zeros((), jnp.float32)),
-                                    (params["layers"], flags))
+    hybrid_fn = remat_wrap(hybrid, remat)
+    e = x
+    L = cfg.num_layers
+    aux = jnp.zeros((), jnp.float32)
+    caches = []
+    for start, stop, call in hybrid_segments(cfg):
+        if call is None:
+            (x, aux), ys = jax.lax.scan(
+                body_fn, (x, aux), (_rows(params["layers"], start, stop, L),
+                                    _rows(flags, start, stop, L)))
+        else:
+            x, ys = hybrid_fn(x, _row(params["layers"], start),
+                              _row(params["shared_blocks"],
+                                   call % cfg.num_mem_blocks),
+                              _row(params["hybrid"], call))
+        caches.append((call, ys))
+    if not collect_cache:
+        caches = None
+    elif len(caches) == 1:
+        caches = caches[0][1]
+    else:
+        # (conv, ssm) over every layer, then (k, v) over the calls
+        mamba = [ys[:2] if call is None
+                 else tuple(a[None] for a in ys[:2]) for call, ys in caches]
+        attn_kv = [ys[2:] for call, ys in caches if call is not None]
+        caches = tuple(jnp.concatenate(a) for a in zip(*mamba)) + \
+            tuple(jnp.stack(a) for a in zip(*attn_kv))
     x = rmsnorm(x, params["final_norm"], cfg.norm_eps)
     if return_hidden:
         return x, aux, caches
@@ -241,7 +355,8 @@ def lm_forward(params, cfg: ModelConfig, tokens, *, image_embeds=None,
 # --- decode ------------------------------------------------------------------------
 
 def init_lm_cache(cfg: ModelConfig, batch: int, seq_len: int):
-    """Zero cache pytree, stacked over layers (leading L dim)."""
+    """Zero cache pytree, stacked over layers (leading L dim; the hybrid
+    family's k/v over its calls)."""
     L = cfg.num_layers
     dtype = dtype_of(cfg)
     fam = cfg.family
@@ -265,10 +380,12 @@ def init_lm_cache(cfg: ModelConfig, batch: int, seq_len: int):
                          jnp.float32),
     }
     if fam == "hybrid":
+        # one KV cache per call of a shared block (leading n_calls dim)
         a = cfg.attention
-        hd = cfg.head_dim
-        cache["k"] = jnp.zeros((L, batch, seq_len, a.num_kv_heads, hd), dtype)
-        cache["v"] = jnp.zeros((L, batch, seq_len, a.num_kv_heads, hd), dtype)
+        shape = (len(cfg.hybrid_layer_ids), batch, seq_len, a.num_kv_heads,
+                 cfg.head_dim)
+        cache["k"] = jnp.zeros(shape, dtype)
+        cache["v"] = jnp.zeros(shape, dtype)
     return cache
 
 
@@ -313,12 +430,13 @@ def lm_decode_step(params, cfg: ModelConfig, cache, tokens, pos):
     """tokens: (B,1) int32; pos: scalar int32. Returns (logits, new_cache)."""
     dtype = dtype_of(cfg)
     eps = cfg.norm_eps
-    x = embed(params["embedding"], tokens, dtype) * math.sqrt(cfg.d_model)
+    x = embed(params["embedding"], tokens, dtype)
+    if cfg.embed_scale:
+        x = x * math.sqrt(cfg.d_model)
     flags = layer_flags(cfg)
-    shared_block = params.get("shared_block")
     fam = cfg.family
 
-    def body(carry, xs):
+    def body(carry, xs, t=None):
         x = carry
         lp, fl, cache_l = xs
         new_cache = dict(cache_l)
@@ -346,33 +464,48 @@ def lm_decode_step(params, cfg: ModelConfig, cache, tokens, pos):
                 o = mlp(lp["mlp"], h, cfg.gated_mlp)
             x = x + o
         else:
-            h = rmsnorm(x, lp["ssm_norm"], eps)
-            o, (conv_st, ssm_st) = ssm_lib.mamba2_decode(
-                lp["ssm"], h, cfg.ssm, conv_state=cache_l["conv"],
-                ssm_state=cache_l["ssm"], eps=eps)
-            x = x + o
+            with obs.block("mamba"):
+                h = rmsnorm(x if t is None else x + t, lp["ssm_norm"], eps)
+                o, (conv_st, ssm_st) = ssm_lib.mamba2_decode(
+                    lp["ssm"], h, cfg.ssm, conv_state=cache_l["conv"],
+                    ssm_state=cache_l["ssm"], eps=eps,
+                    norm_groups=gate_norm_groups(cfg))
+                x = x + o
             new_cache = {"conv": conv_st.astype(cache_l["conv"].dtype),
                          "ssm": ssm_st}
-            if fam == "hybrid":
-                def with_attn(args):
-                    x, k, v = args
-                    sb = shared_block
-                    h = rmsnorm(x, sb["attn_norm"], eps)
-                    o, k, v = attn.gqa_decode(sb["attn"], h, cfg.attention,
-                                              cache_k=k, cache_v=v, pos=pos)
-                    x = x + o
-                    h = rmsnorm(x, sb["ffn_norm"], eps)
-                    x = x + mlp(sb["mlp"], h, cfg.gated_mlp)
-                    return x, k, v
-
-                x, k, v = jax.lax.cond(fl["apply_attn"], with_attn,
-                                       lambda a: a,
-                                       (x, cache_l["k"], cache_l["v"]))
-                new_cache["k"] = k
-                new_cache["v"] = v
         return x, new_cache
 
-    x, new_cache = jax.lax.scan(body, x, (params["layers"], flags, cache))
+    segments = hybrid_segments(cfg)
+    if len(segments) == 1:
+        x, new_cache = jax.lax.scan(body, x, (params["layers"], flags,
+                                              cache))
+    else:
+        e = x
+        L = cfg.num_layers
+        mamba_cache = {"conv": cache["conv"], "ssm": cache["ssm"]}
+        pieces, ks, vs = [], [], []
+        for start, stop, call in segments:
+            if call is None:
+                x, c = jax.lax.scan(body, x, (
+                    _rows(params["layers"], start, stop, L),
+                    _rows(flags, start, stop, L),
+                    _rows(mamba_cache, start, stop, L)))
+            else:
+                t, k, v = _hybrid_call_decode(
+                    _row(params["shared_blocks"], call % cfg.num_mem_blocks),
+                    _row(params["hybrid"], call), x, e, cfg,
+                    cache["k"][call], cache["v"][call], pos)
+                x, c = body(x, (_row(params["layers"], start),
+                                _row(flags, start),
+                                _row(mamba_cache, start)), t)
+                c = jax.tree_util.tree_map(lambda a: a[None], c)
+                ks.append(k)
+                vs.append(v)
+            pieces.append(c)
+        new_cache = {name: jnp.concatenate([p[name] for p in pieces])
+                     for name in ("conv", "ssm")}
+        new_cache["k"] = jnp.stack(ks)
+        new_cache["v"] = jnp.stack(vs)
     x = rmsnorm(x, params["final_norm"], cfg.norm_eps)
     logits = unembed(x, embedding=params.get("embedding")
                      if cfg.tie_embeddings else None,

@@ -6,6 +6,10 @@ host-copy counter, all read from one ``jax.profiler`` trace.
   carries ``repro.<name>`` in its ``op_name`` metadata. Metadata only;
   the numerics and the compiled schedule do not change. Phases do not
   nest.
+- ``block(name)`` is ``jax.named_scope("repro.block.<name>")`` around one
+  block of the model's forward (a Mamba layer, a hybrid call of a shared
+  block): inside ``repro.grad`` on the round's path, never a phase of its
+  own. Metadata only, as for phases.
 - ``span(name)`` is ``jax.profiler.TraceAnnotation("repro.<name>")``
   around a step of a host loop: written into the trace, on the device
   ops' clock, while the profiler runs; about a microsecond otherwise.
@@ -21,6 +25,8 @@ import numpy as np
 PREFIX = "repro."
 # device phases of a round (named scopes)
 PHASES = ("schedule", "grad", "codec", "mac_decode", "optim")
+# kinds of model block (named scopes under the grad phase)
+BLOCKS = ("mamba", "shared")
 # steps of the engine's host loop (host spans)
 SPANS = ("init", "dispatch", "fetch", "eval")
 
@@ -32,6 +38,13 @@ def phase(name: str):
     if name not in PHASES:
         raise ValueError(f"unknown phase {name!r}; phases are {PHASES}")
     return jax.named_scope(PREFIX + name)
+
+
+def block(name: str):
+    """Named scope ``repro.block.<name>`` around one block of the model."""
+    if name not in BLOCKS:
+        raise ValueError(f"unknown block {name!r}; blocks are {BLOCKS}")
+    return jax.named_scope(PREFIX + "block." + name)
 
 
 def span(name: str):

@@ -105,7 +105,49 @@ def test_phase_scopes_do_not_nest(program, request):
     assert phases and max(len(p) for p in phases) == 1
 
 
-@pytest.mark.parametrize("make", [obs.phase, obs.span])
+@pytest.fixture(scope="module")
+def zamba2_hlo():
+    """Compiled text of the zoo-train round of the two-layer Zamba2 smoke
+    model (both layers hybrid: Mamba blocks and shared-block calls)."""
+    model = build_model(get_smoke_config("zamba2-7b"))
+    ob = OBCSAAConfig(chunk=4096, measure=64, topk=16, biht_iters=2,
+                      recon_alg="iht", spmd_topk=True, packed=True,
+                      bisect_iters=16)
+    zr = build_zoo_train_round(model, make_host_mesh(), ob,
+                               optimizer="adam", error_feedback=True)
+    state = zr.shard_state(zr.init_state(zr.chunk_params(
+        model.init(jax.random.PRNGKey(0)))))
+    tok = jnp.zeros((zr.U, 1, 32), jnp.int32)
+    batch = zr.shard_batch({"tokens": tok, "targets": tok})
+    fn = zr._fns(batch)["round_train"]
+    return fn.lower(state, batch, 0, jax.random.PRNGKey(1), 1e-4, 10.0,
+                    0.1).compile().as_text()
+
+
+def _scopes(hlo):
+    """The distinct ``repro.`` scope names (dotted) of each op_name."""
+    return [sorted(set(re.findall(r"repro\.[\w.]*\w", name)))
+            for name in OP_NAME.findall(hlo)]
+
+
+@pytest.mark.parametrize("block", ["mamba", "shared"])
+def test_block_scopes_lie_only_under_the_grad_phase(zamba2_hlo, block):
+    scopes = _scopes(zamba2_hlo)
+    name = "repro.block." + block
+    with_block = [s for s in scopes if name in s]
+    assert with_block
+    assert all("repro.grad" in s for s in with_block)
+
+
+def test_zamba2_round_ops_carry_one_phase_each(zamba2_hlo):
+    phases = [[n for n in s if not n.startswith("repro.block.")]
+              for s in _scopes(zamba2_hlo)]
+    assert phases and max(len(p) for p in phases) == 1
+    for phase in ("grad", "codec", "mac_decode", "optim"):
+        assert ["repro." + phase] in phases
+
+
+@pytest.mark.parametrize("make", [obs.phase, obs.span, obs.block])
 def test_unknown_names_are_refused(make):
     with pytest.raises(ValueError, match="unknown"):
         make("decode")

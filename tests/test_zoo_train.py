@@ -182,8 +182,7 @@ def test_scanned_vs_unrolled_layer_stack_bitwise():
         x, aux_acc = carry
         lp, fl = xs
         x = constrain(x, ("data", None, None))
-        x, _, aux = _apply_layer_full(lp, x, cfg, fl, positions,
-                                      params.get("shared_block"))
+        x, _, aux = _apply_layer_full(lp, x, cfg, fl, positions)
         return (x, aux_acc + aux), None
 
     @jax.jit
